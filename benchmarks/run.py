@@ -1,11 +1,14 @@
 """Benchmark harness: one module per paper table/figure + the roofline
-aggregation.  Prints ``name,us_per_call,derived`` CSV (and a summary)."""
+aggregation.  Prints ``name,us_per_call,derived`` CSV (and a summary).
+Exits non-zero when any module raised: its ERROR row is a failure, not a
+result."""
 import sys
 import time
 
 
-def main() -> None:
-    mods = []
+def main() -> int:
+    from repro.launch.compile_cache import configure_compile_cache
+    configure_compile_cache()
     from benchmarks import (backend_cold_start, chain_e2e, cluster_scale,
                             elastic_shards, fig4_fetch, fig5_warming,
                             hot_path, pool_load, prediction_quality,
@@ -27,6 +30,7 @@ def main() -> None:
             ("roofline", roofline)]
     only = sys.argv[1] if len(sys.argv) > 1 else None
     print("name,us_per_call,derived")
+    failed = []
     for name, mod in mods:
         if only and only != name:
             continue
@@ -35,12 +39,17 @@ def main() -> None:
             rows = mod.run()
         except Exception as e:
             print(f"{name}/ERROR,0,{type(e).__name__}:{e}")
+            failed.append(name)
             continue
         for r in rows:
             print(",".join(str(x) for x in r))
         print(f"# {name} finished in {time.monotonic()-t0:.1f}s",
               file=sys.stderr)
+    if failed:
+        print(f"# FAILED: {', '.join(failed)}", file=sys.stderr)
+        return 1
+    return 0
 
 
 if __name__ == '__main__':
-    main()
+    sys.exit(main())

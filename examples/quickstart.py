@@ -13,6 +13,9 @@ from repro.core import (Connection, FreshenPlan, FunctionSpec, PlanEntry,
                         Runtime, TIERS)
 from repro.core.freshen import Action
 from repro.serving import TieredDatastore
+from repro.launch.compile_cache import configure_compile_cache
+
+configure_compile_cache()
 
 # --- the external resources λ touches (constant creds/ids -> freshenable)
 root = tempfile.mkdtemp(prefix="quickstart-")

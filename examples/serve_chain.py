@@ -33,6 +33,7 @@ import jax
 import numpy as np
 
 from repro.configs import get_config
+from repro.launch.compile_cache import configure_compile_cache
 from repro.models import make_model
 from repro.serving import (Batcher, Executor, ModelEndpoint, ServingEngine,
                            WeightStore, pad_batch)
@@ -91,6 +92,7 @@ if __name__ == "__main__":
     ap = argparse.ArgumentParser()
     ap.add_argument("--requests", type=int, default=8)
     args = ap.parse_args()
+    configure_compile_cache()
 
     for mode in (False, True):
         eng, stages = build(freshen_on=mode)

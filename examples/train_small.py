@@ -18,11 +18,13 @@ import jax
 
 from repro.configs import get_config
 from repro.data import DataConfig, packed_batches
+from repro.launch.compile_cache import configure_compile_cache
 from repro.models import make_model
 from repro.serving import WeightStore
 from repro.train import OptimizerConfig, Trainer, TrainerConfig
 
 if __name__ == "__main__":
+    configure_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--steps", type=int, default=60)
     ap.add_argument("--dim", type=int, default=192)

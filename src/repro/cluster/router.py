@@ -764,12 +764,16 @@ class ClusterRouter:
 
 def partition_devices(devices: Optional[Sequence], num_shards: int
                       ) -> List[Optional[list]]:
-    """Round-robin a device list into ``num_shards`` slices (``None``
-    slices when there are no devices, or fewer devices than shards —
-    pinning is best-effort, never a requirement)."""
+    """Round-robin a device list into ``num_shards`` slices.  No devices
+    means no pinning (``None`` slices); a device list too short to give
+    every shard its own device is an error, never a silently unpinned
+    shard."""
     if not devices:
         return [None] * num_shards
+    if len(devices) < num_shards:
+        raise ValueError(f"{num_shards} shards need at least {num_shards} "
+                         f"devices to pin; got {len(devices)}")
     slices: List[list] = [[] for _ in range(num_shards)]
     for i, d in enumerate(devices):
         slices[i % num_shards].append(d)
-    return [s or None for s in slices]
+    return slices

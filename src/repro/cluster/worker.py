@@ -62,17 +62,13 @@ class ClusterWorker:
             tracer=tracer, fast_path=fast_path)
 
     # -- registration ---------------------------------------------------
-    def _pinned(self, code):
-        """Wrap a function body so it runs with this shard's first device
-        as the jax default — invocations on different shards then place
-        their arrays on different hardware."""
-        devices = self.devices
-
-        def run_pinned(ctx, args):
-            import jax
-            with jax.default_device(devices[0]):
-                return code(ctx, args)
-        return run_pinned
+    def _placement(self):
+        """Context pinning this shard's first device as the jax default:
+        every hook of the shard's instances (init, freshen, run) runs
+        inside it, so weights, executables and warm-ups land on the
+        shard's own hardware, not on whatever device is the default."""
+        import jax
+        return jax.default_device(self.devices[0])
 
     def register(self, spec: FunctionSpec,
                  config: Optional[PoolConfig] = None,
@@ -81,8 +77,8 @@ class ClusterWorker:
         saturation errors name the shard.  ``backend`` selects the
         instance backend (repro.core.backend: thread, subprocess, or
         snapshot — a snapshot pool's fork template lives and dies with
-        this shard's pools); device pinning wraps the function body in a
-        closure and therefore requires the in-process thread backend."""
+        this shard's pools); device pinning is a per-thread jax context
+        and therefore requires the in-process thread backend."""
         if self.devices:
             chosen = backend or (config.backend if config
                                  else self.scheduler.pool_config.backend)
@@ -90,7 +86,7 @@ class ClusterWorker:
                 raise ValueError(
                     f"shard {self.shard_id} pins jax devices, which "
                     f"requires the thread backend (got {chosen!r})")
-            spec = dataclasses.replace(spec, code=self._pinned(spec.code))
+            spec = dataclasses.replace(spec, placement=self._placement)
         rt = self.scheduler.register(spec, config=config, backend=backend)
         self.scheduler.pools[spec.name].shard = self.shard_id
         return rt

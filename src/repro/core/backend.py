@@ -29,9 +29,10 @@ execution substrate a policy choice:
 
 A backend instance is per-``Runtime`` (it owns the worker process or the
 forked instance); selection is per-pool via ``PoolConfig.backend`` and
-threads through ``FreshenScheduler.register(..., backend=...)``,
-``ClusterWorker.register(..., backend=...)`` and
-``ServingEngine.deploy(..., backend=...)``.  The snapshot template itself
+threads through ``FreshenScheduler.register(..., backend=...)`` and
+``ClusterWorker.register(..., backend=...)``.  Model endpoints
+(``ServingEngine.deploy``) stay on the thread backend: a child process
+cannot reach an accelerator its parent holds.  The snapshot template itself
 is pool-owned — one per (function, pool), started at pool construction
 and closed with the pool — so fork economics are shared across every
 instance the pool ever provisions.

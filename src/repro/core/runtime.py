@@ -42,11 +42,12 @@ threads, counters — identical across backends.
 """
 from __future__ import annotations
 
+import contextlib
 import enum
 import threading
 import time
-from dataclasses import dataclass, field
-from typing import Any, Callable, Dict, Optional
+from dataclasses import dataclass
+from typing import Any, Callable, ContextManager, Dict, Optional
 
 from repro.core.cache import FreshenCache
 from repro.core.freshen import FreshenPlan, FreshenState
@@ -84,6 +85,11 @@ class FunctionSpec:
     # worker process — to this spec or to a zero-arg factory returning
     # it, for specs whose callables are closures and cannot pickle
     ref: Optional[str] = None
+    # where the instance's work lands: a zero-arg factory of the context
+    # every hook (init, freshen, run) executes inside — e.g. a jax
+    # default-device pin, so weights, compiles and warm-ups made on the
+    # freshen thread land where the run hook executes.  Thread backend.
+    placement: Optional[Callable[[], ContextManager]] = None
 
 
 class RunContext:
@@ -206,6 +212,10 @@ class Runtime:
             self.backend.demote(self, level)     # fabriclint: allow[blocking]
             self.warmth = level
 
+    def _placed(self) -> ContextManager:
+        placement = self.spec.placement
+        return placement() if placement else contextlib.nullcontext()
+
     def _promote_locked(self, target: WarmthLevel) -> None:
         if self.warmth >= target:
             return
@@ -227,7 +237,7 @@ class Runtime:
             if target >= WarmthLevel.INITIALIZED \
                     and self.warmth < WarmthLevel.INITIALIZED:
                 t0 = self.clock()
-                with span.phase("boot_init"):
+                with span.phase("boot_init"), self._placed():
                     self.backend.boot_init(self)     # fabriclint: allow[blocking]
                 self.init_step_seconds = self.clock() - t0
                 self.warmth = WarmthLevel.INITIALIZED
@@ -262,7 +272,8 @@ class Runtime:
 
         def _run():
             self._ensure_init()
-            self.backend.freshen(self)
+            with self._placed():
+                self.backend.freshen(self)
             self._set_warmth_at_least(WarmthLevel.HOT)
 
         if blocking:
@@ -281,7 +292,8 @@ class Runtime:
         so a completed run leaves the instance HOT."""
         self._ensure_init()
         self.run_count += 1
-        result = self.backend.run(self, args)
+        with self._placed():
+            result = self.backend.run(self, args)
         self._set_warmth_at_least(WarmthLevel.HOT)
         return result
 

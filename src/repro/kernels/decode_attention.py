@@ -20,7 +20,7 @@ NEG_INF = -1e30
 
 def _decode_kernel(pos_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref,
                    *, scale, softcap, window, ring, kv_blk, nk):
-    ki = pl.program_id(2)
+    bi, ki = pl.program_id(0), pl.program_id(2)
 
     @pl.when(ki == 0)
     def _init():
@@ -31,7 +31,7 @@ def _decode_kernel(pos_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref,
     q = q_ref[0, 0].astype(jnp.float32)                 # (1, dh)
     k = k_ref[0, 0].astype(jnp.float32)                 # (kv_blk, dh)
     v = v_ref[0, 0].astype(jnp.float32)                 # (kv_blk, dv)
-    pos = pos_ref[0]
+    pos = pos_ref[bi]
 
     s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                             preferred_element_type=jnp.float32) * scale
@@ -87,19 +87,21 @@ def decode_attention_kernel(q, k_cache, v_cache, pos, *, window=None,
         _decode_kernel, scale=scale, softcap=softcap, window=window,
         ring=ring, kv_blk=kv_blk, nk=nk)
 
+    # pos is a scalar-prefetch operand: the whole (B,) vector sits in
+    # SMEM and each program reads its row's entry (index maps receive the
+    # prefetched ref as a trailing argument)
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=0,
+        num_scalar_prefetch=1,
         grid=(B, Hq, nk),
         in_specs=[
-            pl.BlockSpec((1,), lambda b, h, ki: (b,),
-                         memory_space=pltpu.SMEM),
-            pl.BlockSpec((1, 1, 1, dh), lambda b, h, ki: (b, h, 0, 0)),
+            pl.BlockSpec((1, 1, 1, dh), lambda b, h, ki, _: (b, h, 0, 0)),
             pl.BlockSpec((1, 1, kv_blk, dh),
-                         lambda b, h, ki, G=G: (b, h // G, ki, 0)),
+                         lambda b, h, ki, _, G=G: (b, h // G, ki, 0)),
             pl.BlockSpec((1, 1, kv_blk, dv),
-                         lambda b, h, ki, G=G: (b, h // G, ki, 0)),
+                         lambda b, h, ki, _, G=G: (b, h // G, ki, 0)),
         ],
-        out_specs=pl.BlockSpec((1, 1, 1, dv), lambda b, h, ki: (b, h, 0, 0)),
+        out_specs=pl.BlockSpec((1, 1, 1, dv),
+                               lambda b, h, ki, _: (b, h, 0, 0)),
         scratch_shapes=[
             pltpu.VMEM((1,), jnp.float32),
             pltpu.VMEM((1,), jnp.float32),

@@ -23,18 +23,13 @@ from jax.experimental.pallas import tpu as pltpu
 
 
 def _rglru_kernel(a_ref, b_ref, h0_ref, y_ref, hT_ref, *, seq_len):
-    a = a_ref[0]                                        # (S, r_blk) f32
-    b = b_ref[0]
-    h0 = h0_ref[0]                                      # (r_blk,)
-
-    def step(t, carry):
-        h = carry
-        h = a[t] * h + b[t]
-        y_ref[0, t] = h
+    def step(t, h):                                     # h: (1, r_blk) f32
+        row = pl.ds(t, 1)
+        h = a_ref[0, row, :] * h + b_ref[0, row, :]
+        y_ref[0, row, :] = h
         return h
 
-    hT = jax.lax.fori_loop(0, seq_len, step, h0)
-    hT_ref[0] = hT
+    hT_ref[0] = jax.lax.fori_loop(0, seq_len, step, h0_ref[0])
 
 
 def rglru_scan_kernel(a, b, h0=None, *, b_blk=1, r_blk=256, interpret=False):
@@ -48,6 +43,8 @@ def rglru_scan_kernel(a, b, h0=None, *, b_blk=1, r_blk=256, interpret=False):
     if h0 is None:
         h0 = jnp.zeros((B, r), jnp.float32)
 
+    # the carried state travels as (B, 1, r): a (1, r_blk) block then
+    # equals the array's second-minor dim, as Mosaic's (8, 128) rule asks
     kernel = functools.partial(_rglru_kernel, seq_len=S)
     y, hT = pl.pallas_call(
         kernel,
@@ -55,16 +52,17 @@ def rglru_scan_kernel(a, b, h0=None, *, b_blk=1, r_blk=256, interpret=False):
         in_specs=[
             pl.BlockSpec((1, S, r_blk), lambda bi, ri: (bi, 0, ri)),
             pl.BlockSpec((1, S, r_blk), lambda bi, ri: (bi, 0, ri)),
-            pl.BlockSpec((1, r_blk), lambda bi, ri: (bi, ri)),
+            pl.BlockSpec((1, 1, r_blk), lambda bi, ri: (bi, 0, ri)),
         ],
         out_specs=[
             pl.BlockSpec((1, S, r_blk), lambda bi, ri: (bi, 0, ri)),
-            pl.BlockSpec((1, r_blk), lambda bi, ri: (bi, ri)),
+            pl.BlockSpec((1, 1, r_blk), lambda bi, ri: (bi, 0, ri)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((B, S, r), jnp.float32),
-            jax.ShapeDtypeStruct((B, r), jnp.float32),
+            jax.ShapeDtypeStruct((B, 1, r), jnp.float32),
         ],
         interpret=interpret,
-    )(a.astype(jnp.float32), b.astype(jnp.float32), h0.astype(jnp.float32))
-    return y, hT
+    )(a.astype(jnp.float32), b.astype(jnp.float32),
+      h0.astype(jnp.float32)[:, None, :])
+    return y, hT[:, 0]

@@ -1,5 +1,7 @@
-"""Serving launcher: deploy model endpoints behind the freshen platform and
-drive synthetic load through a chain.
+"""Serving launcher: deploy model endpoints of the named config, at its
+published widths, behind the freshen platform and drive synthetic load
+through a chain.  Everything runs in this one process (the process that
+holds the accelerator); weights are random, made from ``--seed``.
 
   PYTHONPATH=src python -m repro.launch.serve --stages 3 --requests 8
   PYTHONPATH=src python -m repro.launch.serve --no-freshen ...   # baseline
@@ -9,7 +11,6 @@ from __future__ import annotations
 # wall-clock stamps feed the printed timings only.
 
 import argparse
-import dataclasses
 import tempfile
 import time
 
@@ -24,6 +25,7 @@ def main():
     ap.add_argument("--requests", type=int, default=6)
     ap.add_argument("--batch", type=int, default=2)
     ap.add_argument("--seq", type=int, default=16)
+    ap.add_argument("--seed", type=int, default=0)
     ap.add_argument("--no-freshen", action="store_true")
     ap.add_argument("--service-class", default="latency_sensitive",
                     choices=["latency_sensitive", "standard", "batch"])
@@ -31,38 +33,42 @@ def main():
 
     from repro.configs import get_config
     from repro.core.accounting import ServiceClass
+    from repro.launch.compile_cache import configure_compile_cache
     from repro.models import make_model
     from repro.serving import (Executor, ModelEndpoint, ServingEngine,
                                WeightStore)
 
+    configure_compile_cache()
     freshen_on = not args.no_freshen
+    cfg = get_config(args.arch)
     store = WeightStore(tempfile.mkdtemp(prefix="serve-"))
     eng = ServingEngine()
     eng.scheduler.accountant.service_class["serving"] = \
         ServiceClass(args.service_class)
     names = [f"stage{i}" for i in range(args.stages)]
     for i, name in enumerate(names):
-        cfg = get_config(args.arch).reduced(d_model=128)
-        cfg = dataclasses.replace(cfg, vocab_size=256)
-        store.publish(name, make_model(cfg).init(jax.random.PRNGKey(i)))
+        store.publish(name, make_model(cfg).init(
+            jax.random.PRNGKey(args.seed + i)))
         eng.deploy(ModelEndpoint(name, cfg, store, Executor(),
                                  batch_size=args.batch, seq_len=args.seq))
     if freshen_on:
         eng.chain(names)
 
-    rng = np.random.default_rng(0)
+    rng = np.random.default_rng(args.seed)
     lat = {n: [] for n in names}
     t0 = time.monotonic()
-    for r in range(args.requests):
-        toks = rng.integers(0, 256, size=(args.batch, args.seq),
-                            dtype=np.int32)
-        for n in names:
-            if freshen_on and n != names[0]:
-                eng.scheduler.runtimes[n].join_freshen(timeout=120)
-            out = eng.invoke(n, toks, freshen_successors=freshen_on)
-            lat[n].append(out["timing"]["total"])
-    wall = time.monotonic() - t0
-
+    try:
+        for r in range(args.requests):
+            toks = rng.integers(0, cfg.vocab_size,
+                                size=(args.batch, args.seq), dtype=np.int32)
+            for n in names:
+                if freshen_on and n != names[0]:
+                    eng.scheduler.runtimes[n].join_freshen(timeout=120)
+                out = eng.invoke(n, toks, freshen_successors=freshen_on)
+                lat[n].append(out["timing"]["total"])
+        wall = time.monotonic() - t0
+    finally:
+        eng.close()
     print(f"mode={'freshen' if freshen_on else 'baseline'} "
           f"requests={args.requests} wall={wall:.2f}s")
     for n in names:

@@ -20,6 +20,7 @@ from __future__ import annotations
 
 import threading
 import time
+from concurrent.futures import Future
 from dataclasses import dataclass, field
 from typing import Any, Callable, Dict, List, Optional
 
@@ -59,8 +60,7 @@ class ModelEndpoint:
                  batch_size: int = 4, seq_len: int = 64, app: str = "serving",
                  datastore=None, prefetch_key: Optional[str] = None,
                  prefetch_ttl: Optional[float] = None,
-                 warm_budget: Optional[WarmBudget] = None,
-                 spec_ref: Optional[str] = None):
+                 warm_budget: Optional[WarmBudget] = None):
         self.name = name
         self.cfg = cfg
         self.model = make_model(cfg)
@@ -73,10 +73,6 @@ class ModelEndpoint:
         self.prefetch_key = prefetch_key
         self.prefetch_ttl = prefetch_ttl
         self.warm_budget = warm_budget or WarmBudget(min_repetitions=0)
-        # "module:attr" the subprocess backend's worker can import to
-        # rebuild this endpoint's FunctionSpec (endpoint state does not
-        # pickle); None keeps the endpoint thread-backend-only
-        self.spec_ref = spec_ref
         self.timings: List[dict] = []
 
     # ------------------------------------------------------------------
@@ -224,8 +220,7 @@ class ModelEndpoint:
 
     def spec(self) -> FunctionSpec:
         return FunctionSpec(self.name, self.code,
-                            plan_factory=self.build_plan, app=self.app,
-                            ref=self.spec_ref)
+                            plan_factory=self.build_plan, app=self.app)
 
 
 class ServingEngine:
@@ -237,11 +232,17 @@ class ServingEngine:
     across warm instances, scale the pool up under queue pressure, and are
     prewarmed by predicted-successor freshen dispatch.  ``deploy`` eagerly
     initializes the primary instance (the seed-era warm container);
-    additional instances cold-start on demand."""
+    additional instances cold-start on demand.
+
+    The sharded fabric pins each shard to a device of its own, taken from
+    ``devices`` (default: ``jax.local_devices()``): a shard's weights,
+    executables and warm-ups land on that device.  A fabric wider than
+    the device list is refused; a caller that means several shards to
+    share hardware says so by listing a device more than once."""
 
     def __init__(self, scheduler=None, router_policy: str = "warmth-aware",
                  spill_timeout: Optional[float] = None,
-                 tracer=None):
+                 tracer=None, devices: Optional[List[Any]] = None):
         from repro.core.scheduler import FreshenScheduler
         # one tracer for the whole engine: the base scheduler and (if a
         # fabric is built) every shard share it, so exported traces show
@@ -262,6 +263,10 @@ class ServingEngine:
         self.router_policy = router_policy
         self.spill_timeout = spill_timeout
         self._clustered: set = set()          # endpoint names on the fabric
+        # device slots the fabric's shards are pinned to (resolved at the
+        # first sharded deploy); ``_slot_of`` maps shard id -> slot
+        self._devices = None if devices is None else list(devices)
+        self._slot_of: Dict[int, int] = {}
 
     def _default_pool_config(self):
         # model endpoints hold multi-second XLA compiles and weight
@@ -272,9 +277,20 @@ class ServingEngine:
         return dataclasses.replace(self.scheduler.pool_config,
                                    keep_alive=600.0)
 
+    def _device_slots(self) -> List[Any]:
+        if self._devices is None:
+            self._devices = list(jax.local_devices())
+        return self._devices
+
     def _ensure_cluster(self, shards: int, elastic: bool = False):
         if self.cluster is None:
             from repro.cluster import ClusterRouter
+            devices = self._device_slots()
+            if shards > len(devices):
+                raise ValueError(
+                    f"{shards} shards need {shards} devices to pin, and "
+                    f"this engine has {len(devices)}; pass devices= listing "
+                    f"a device more than once to share it between shards")
             # the fabric shares the engine scheduler's predictor:
             # prediction (chains, periodicity) is global knowledge, so
             # chain() and trace priming keep working unchanged
@@ -282,8 +298,10 @@ class ServingEngine:
                 shards, policy=self.router_policy,
                 pool_config=self.scheduler.pool_config,
                 predictor=self.scheduler.predictor,
+                devices=devices[:shards],
                 spill_timeout=self.spill_timeout,
                 tracer=self.tracer if self.tracer.enabled else None)
+            self._slot_of = {k: k for k in range(shards)}
         elif shards > self.cluster.num_shards:
             if not elastic:
                 raise ValueError(
@@ -291,8 +309,20 @@ class ServingEngine:
                     f"shards; deploy the widest endpoint first (asked for "
                     f"{shards}) or pass elastic=True to grow the fleet")
             while self.cluster.num_shards < shards:
-                self.cluster.add_worker()
+                self._add_shard()
         return self.cluster
+
+    def _add_shard(self):
+        """Grow the fabric by one shard, pinned to a device slot no live
+        shard holds (a drained shard's slot is free again)."""
+        live = {w.shard_id for w in self.cluster.workers}
+        held = {slot for sid, slot in self._slot_of.items() if sid in live}
+        free = [i for i in range(len(self._devices)) if i not in held]
+        if not free:
+            raise ValueError(f"no free device for another shard: all "
+                             f"{len(self._devices)} devices hold one")
+        worker = self.cluster.add_worker(devices=[self._devices[free[0]]])
+        self._slot_of[worker.shard_id] = free[0]
 
     def scale_shards(self, n: int, drain: bool = True) -> int:
         """Resize the sharded fabric to ``n`` shards at runtime.
@@ -313,7 +343,7 @@ class ServingEngine:
             self._ensure_cluster(n)
             return self.cluster.num_shards
         while self.cluster.num_shards < n:
-            self.cluster.add_worker()
+            self._add_shard()
         while self.cluster.num_shards > n:
             victim = max(w.shard_id for w in self.cluster.workers)
             self.cluster.remove_worker(victim, drain=drain)
@@ -321,7 +351,6 @@ class ServingEngine:
 
     def deploy(self, ep: ModelEndpoint, pool_config=None,
                shards: Optional[int] = None,
-               backend: Optional[str] = None,
                elastic: bool = False,
                graded_warmth: Optional[bool] = None,
                batch_size: Optional[int] = None,
@@ -333,15 +362,9 @@ class ServingEngine:
         shard-0 primary is eagerly initialized — the other shards warm up
         on demand or by prewarm, which is the point of the fabric.
 
-        ``backend`` selects the instance backend (repro.core.backend):
-        ``"subprocess"`` runs each instance in its own worker process so
-        cold starts are measured interpreter+import time;
-        ``"snapshot"`` forks instances from a pre-warmed per-pool
-        template process so cold starts collapse to measured
-        fork + init_fn time.  A stock ``ModelEndpoint``'s spec closes
-        over live JAX state, so out-of-process deploys need an importable
-        spec — set ``FunctionSpec.ref`` (``"module:attr"``) on the spec
-        the worker should rebuild.
+        Endpoints run on the in-process thread backend: their instances
+        share the process that holds the accelerator, which a child
+        process could not reach.
 
         ``elastic=True`` makes the deploy fleet-elastic: asking for more
         shards than the fabric currently has grows it (instead of
@@ -371,9 +394,11 @@ class ServingEngine:
         self.endpoints[ep.name] = ep
         if pool_config is None:
             pool_config = self._default_pool_config()
-        if backend is not None:
-            import dataclasses
-            pool_config = dataclasses.replace(pool_config, backend=backend)
+        if pool_config.backend != "thread":
+            raise ValueError(
+                f"endpoint {ep.name!r}: serving runs on the thread backend "
+                f"(the process holding the accelerator), not "
+                f"{pool_config.backend!r}")
         if graded_warmth is not None:
             import dataclasses
             pool_config = dataclasses.replace(pool_config,

@@ -2,7 +2,10 @@
 
 ``jit`` compilation is the TPU/JAX cold start (seconds of wall time) —
 ``CompileResource`` freshens it by compiling ahead of the predicted
-invocation.  The cache is keyed by (name, shapes) and is runtime-scoped.
+invocation.  The cache is keyed by (name, shapes, target device) and is
+runtime-scoped: an executable compiled under one shard's device pin is
+never handed to a shard pinned elsewhere (it would silently move that
+shard's arguments to the first device).
 """
 from __future__ import annotations
 # fabriclint: allow-file[clock] -- compile/warmup seconds are measured
@@ -27,7 +30,10 @@ class Executor:
     @staticmethod
     def _key(name: str, specs) -> Tuple:
         leaves = jax.tree.leaves(specs)
-        return (name,) + tuple((tuple(l.shape), str(l.dtype)) for l in leaves)
+        # the thread's jax default device decides where the AOT compile
+        # places the program (None = the backend's first device)
+        return ((name, jax.config.jax_default_device)
+                + tuple((tuple(l.shape), str(l.dtype)) for l in leaves))
 
     # ------------------------------------------------------------------
     def compile(self, name: str, fn: Callable, specs, *,

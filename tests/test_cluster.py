@@ -402,7 +402,8 @@ def test_partition_devices_round_robin():
     assert partition_devices(None, 3) == [None, None, None]
     assert partition_devices(list("abcde"), 2) == [["a", "c", "e"],
                                                    ["b", "d"]]
-    assert partition_devices(list("ab"), 4) == [["a"], ["b"], None, None]
+    with pytest.raises(ValueError, match="4 shards need at least 4"):
+        partition_devices(list("ab"), 4)
 
 
 def test_worker_shard_tags_and_signals():
@@ -430,8 +431,10 @@ class _StubEndpoint:
 
 
 def test_engine_deploy_shards_routes_through_cluster():
+    import jax
     from repro.serving.engine import ServingEngine
-    eng = ServingEngine()
+    # two shards sharing the one CPU device, listed twice on purpose
+    eng = ServingEngine(devices=jax.local_devices()[:1] * 2)
     try:
         eng.deploy(_StubEndpoint("sharded"), pool_config=PoolConfig(
             max_instances=2, keep_alive=60.0), shards=2)
@@ -448,3 +451,45 @@ def test_engine_deploy_shards_routes_through_cluster():
             eng.deploy(_StubEndpoint("wider"), shards=4)
     finally:
         eng.close()
+
+
+def test_engine_refuses_fabric_wider_than_its_devices():
+    import jax
+    from repro.serving.engine import ServingEngine
+    eng = ServingEngine(devices=jax.local_devices()[:1])
+    try:
+        with pytest.raises(ValueError, match="2 shards need 2 devices"):
+            eng.deploy(_StubEndpoint("too-wide"), shards=2)
+        assert eng.cluster is None
+    finally:
+        eng.close()
+
+
+def test_pinned_shard_runs_every_hook_on_its_device():
+    """A pinned shard's init, freshen and run hooks all see the shard's
+    device as the jax default — not only the function body."""
+    import jax
+    dev = jax.local_devices()[0]
+    seen = {}
+
+    def init_fn(rt):
+        seen["init"] = jax.config.jax_default_device
+
+    def plan(rt):
+        return FreshenPlan([PlanEntry(
+            "probe", Action.FETCH,
+            lambda: seen.setdefault("freshen", jax.config.jax_default_device))])
+
+    def code(ctx, args):
+        seen["run"] = jax.config.jax_default_device
+        return ctx.fr_fetch(0)
+
+    w = ClusterWorker(0, devices=[dev])
+    try:
+        rt = w.register(FunctionSpec("pinned", code, plan_factory=plan,
+                                     init_fn=init_fn))
+        rt.freshen(blocking=True)
+        assert rt.run({}) == dev
+        assert seen == {"init": dev, "freshen": dev, "run": dev}
+    finally:
+        w.shutdown()

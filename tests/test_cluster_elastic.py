@@ -541,9 +541,16 @@ class _StubEndpoint:
         return make_spec(self.name, app="serving-elastic")
 
 
+def _shared_cpu(n):
+    """``n`` device slots on the one CPU device: the engine pins one shard
+    per slot, so host-only stub fabrics can grow to ``n`` shards."""
+    import jax
+    return jax.local_devices()[:1] * n
+
+
 def test_engine_scale_shards_and_elastic_deploy():
     from repro.serving.engine import ServingEngine
-    eng = ServingEngine()
+    eng = ServingEngine(devices=_shared_cpu(4))
     try:
         eng.deploy(_StubEndpoint("ep"), pool_config=PoolConfig(
             max_instances=2, keep_alive=60.0), shards=2, elastic=True)
@@ -568,7 +575,7 @@ def test_engine_scale_shards_and_elastic_deploy():
 
 def test_engine_latency_summary_keeps_drained_shard_history():
     from repro.serving.engine import ServingEngine
-    eng = ServingEngine()
+    eng = ServingEngine(devices=_shared_cpu(4))
     try:
         eng.deploy(_StubEndpoint("ep"), pool_config=PoolConfig(
             max_instances=2, keep_alive=60.0), shards=3, elastic=True)
@@ -587,7 +594,7 @@ def test_engine_latency_summary_keeps_drained_shard_history():
 
 def test_engine_elastic_deploy_without_shards_joins_fabric():
     from repro.serving.engine import ServingEngine
-    eng = ServingEngine()
+    eng = ServingEngine(devices=_shared_cpu(4))
     try:
         eng.scale_shards(2)
         eng.deploy(_StubEndpoint("ep"), pool_config=PoolConfig(
@@ -606,7 +613,7 @@ def test_engine_fixed_width_deploy_after_elastic_churn():
     deploy(shards=N) must target the N lowest live shards, not
     range(N)."""
     from repro.serving.engine import ServingEngine
-    eng = ServingEngine()
+    eng = ServingEngine(devices=_shared_cpu(4))
     try:
         eng.scale_shards(3)                    # ids {0, 1, 2}
         eng.scale_shards(2)                    # drains 2 -> {0, 1}
@@ -622,7 +629,7 @@ def test_engine_fixed_width_deploy_after_elastic_churn():
 
 def test_engine_scale_shards_builds_fabric_first_use():
     from repro.serving.engine import ServingEngine
-    eng = ServingEngine()
+    eng = ServingEngine(devices=_shared_cpu(4))
     try:
         assert eng.scale_shards(1) == 1 and eng.cluster is None
         assert eng.scale_shards(2) == 2 and eng.cluster is not None

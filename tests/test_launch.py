@@ -47,10 +47,8 @@ from repro.models import make_model
 # production mesh shapes (as functions, no import-time device use)
 from repro.launch.mesh import make_production_mesh
 
-mesh_kwargs = {}
-if hasattr(jax.sharding, "AxisType"):       # jax >= 0.5: explicit Auto axes
-    mesh_kwargs["axis_types"] = (jax.sharding.AxisType.Auto,) * 2
-mesh = jax.make_mesh((2, 2), ("data", "model"), **mesh_kwargs)
+mesh = jax.make_mesh((2, 2), ("data", "model"),
+                     axis_types=(jax.sharding.AxisType.Auto,) * 2)
 
 # reduced config through every builder on the tiny mesh
 cfg = dataclasses.replace(get_config("qwen2-0.5b").reduced(d_model=128),
@@ -70,8 +68,6 @@ step, specs, donate, M = st.build_train_step(model, shape_t, mesh,
 with mesh:
     compiled = jax.jit(step, donate_argnums=donate).lower(*specs).compile()
 ca = compiled.cost_analysis()
-if isinstance(ca, (list, tuple)):   # jax <= 0.4.x: one dict per device
-    ca = ca[0]
 assert ca and ca.get("flops", 0) > 0
 
 units = st.build_units(model, shape_t, mesh, microbatches=2)

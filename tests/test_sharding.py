@@ -18,10 +18,8 @@ from repro.sharding import (cache_leaf_spec, param_spec, shard_params,
                             token_spec)
 from repro.launch.steps import resolve_serve_strategy
 
-mesh_kwargs = {}
-if hasattr(jax.sharding, "AxisType"):       # jax >= 0.5: explicit Auto axes
-    mesh_kwargs["axis_types"] = (jax.sharding.AxisType.Auto,) * 2
-mesh = jax.make_mesh((4, 4), ("data", "model"), **mesh_kwargs)
+mesh = jax.make_mesh((4, 4), ("data", "model"),
+                     axis_types=(jax.sharding.AxisType.Auto,) * 2)
 
 # --- param rules
 assert param_spec("embed", (256000, 4608), mesh, "serve") == P("model", None)
