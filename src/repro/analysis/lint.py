@@ -28,9 +28,9 @@ Rules (tags in brackets are what ``# fabriclint: allow[tag]`` suppresses):
   bypasses ``MetricsRegistry``.  The telemetry package itself (the
   implementation layer) is exempt.
 * **R5 span-leak** [span] — a ``tracer.invocation(...)`` /
-  ``tracer.freshen(...)`` span that is neither completed
-  (``finish``/``gated``/``dispatched``) nor escapes the function
-  (returned, stored, passed on) leaks an open span.
+  ``tracer.freshen(...)`` / ``tracer.plan(...)`` span that is neither
+  completed (``finish``/``gated``/``dispatched``) nor escapes the
+  function (returned, stored, passed on) leaks an open span.
 
 Suppression: ``# fabriclint: allow[tag]`` on the finding's line or the
 line above; ``# fabriclint: allow-file[tag]`` anywhere in the file.
@@ -102,7 +102,7 @@ _SUBPROCESS_ATTRS = frozenset({
     "run", "call", "check_call", "check_output", "Popen", "communicate",
 })
 _OS_BLOCKING_ATTRS = frozenset({"fork", "forkpty", "wait", "waitpid", "wait4"})
-_SPAN_FACTORY_ATTRS = frozenset({"invocation", "freshen"})
+_SPAN_FACTORY_ATTRS = frozenset({"invocation", "freshen", "plan"})
 _SPAN_COMPLETING_ATTRS = frozenset({
     "finish", "gated", "dispatched", "dispatch_done",
 })

@@ -54,9 +54,9 @@ def save_pytree(path: str, tree, metadata: dict | None = None) -> None:
             os.unlink(tmp)
 
 
-def load_pytree(path: str, like) -> Any:
-    """Load into the structure of ``like`` (a template pytree or its
-    eval_shape); leaf order is matched by key path."""
+def read_flat(path: str) -> dict:
+    """Read every leaf of a checkpoint into host memory, keyed by path,
+    each in its true dtype (bfloat16 and kin viewed back from uint16)."""
     import ml_dtypes
     with np.load(path, allow_pickle=False) as z:
         meta = json.loads(str(z["__meta__"]))
@@ -64,6 +64,14 @@ def load_pytree(path: str, like) -> Any:
     dtype_map = meta.get("dtype_map", {})
     for k, dt in dtype_map.items():
         flat[k] = flat[k].view(np.dtype(getattr(ml_dtypes, dt)))
+    return flat
+
+
+def place_flat(flat: dict, like) -> Any:
+    """Put the leaves of ``flat`` on the device in the structure of
+    ``like`` (a template pytree or its eval_shape), matched by key path.
+    Each transfer is issued leaf by leaf and may still be in flight when
+    this returns."""
     paths, treedef = jax.tree_util.tree_flatten_with_path(like)
     leaves = []
     for path_elems, leaf in paths:
@@ -74,6 +82,12 @@ def load_pytree(path: str, like) -> Any:
         want = np.dtype(leaf.dtype)
         leaves.append(jax.numpy.asarray(arr).astype(want))
     return jax.tree_util.tree_unflatten(treedef, leaves)
+
+
+def load_pytree(path: str, like) -> Any:
+    """Load into the structure of ``like`` (a template pytree or its
+    eval_shape); leaf order is matched by key path."""
+    return place_flat(read_flat(path), like)
 
 
 def load_metadata(path: str) -> dict:

@@ -218,7 +218,9 @@ class ThreadBackend(InstanceBackend):
             runtime.spec.init_fn(runtime)
         plan = (runtime.spec.plan_factory(runtime)
                 if runtime.spec.plan_factory else FreshenPlan([]))
-        runtime.fr_state = FreshenState(plan, clock=runtime.clock)
+        runtime.fr_state = FreshenState(plan, clock=runtime.clock,
+                                        tracer=runtime.tracer,
+                                        fn=runtime.spec.name)
 
     def demote(self, runtime, level: WarmthLevel) -> None:
         if level < WarmthLevel.INITIALIZED:

@@ -18,6 +18,10 @@ resources have already been freshened by wrapper functions invoked by λ" —
 we include them).  It is invoked in a separate thread by the runtime
 (§3.1: non-blocking, run-hook timing unmodified) and, per the abuse rule,
 receives NO function arguments.
+
+With a tracer, each entry execution is a plan-entry span (``by``
+``freshen`` or ``inline``) and each FrWait a ``fr_wait`` phase of the
+invocation waiting (``repro.telemetry``).
 """
 from __future__ import annotations
 
@@ -26,6 +30,8 @@ import time
 from dataclasses import dataclass, field
 from enum import Enum
 from typing import Any, Callable, List, Optional, Sequence
+
+from repro.telemetry import NULL_TRACER, Tracer, current_span
 
 
 class FrState(Enum):
@@ -80,9 +86,12 @@ class _Entry:
 class FreshenState:
     """fr_state — runtime-scoped, thread-safe."""
 
-    def __init__(self, plan: FreshenPlan, clock: Callable[[], float] = time.monotonic):
+    def __init__(self, plan: FreshenPlan, clock: Callable[[], float] = time.monotonic,
+                 tracer: Optional[Tracer] = None, fn: str = ""):
         self.plan = plan
         self.clock = clock
+        self.tracer = tracer or NULL_TRACER
+        self.fn = fn                      # the function, for plan spans
         self.entries = [_Entry() for _ in plan.entries]
 
     # ------------------------------------------------------------------
@@ -113,11 +122,15 @@ class FreshenState:
                  thunk: Optional[Callable[[], Any]] = None) -> Any:
         e = self.entries[idx]
         pe = self.plan.entries[idx]
+        span = self.tracer.plan(self.fn, pe.name,
+                                "freshen" if by_freshen else "inline")
         try:
-            result = (thunk or pe.thunk)()
+            with span.active():
+                result = (thunk or pe.thunk)()
             err = None
         except BaseException as exc:        # freshen failure is never fatal
             result, err = None, exc
+        span.finish(error=None if err is None else type(err).__name__)
         with e.cond:
             if err is None:
                 e.result = result
@@ -137,7 +150,16 @@ class FreshenState:
         return result
 
     def fr_wait(self, idx: int, timeout: Optional[float] = None):
-        """Algorithm 4/5 line 6: block until the in-flight freshen finishes."""
+        """Algorithm 4/5 line 6: block until the in-flight freshen
+        finishes; a ``fr_wait`` phase of the waiting invocation, if
+        traced."""
+        span = current_span()
+        if span is None:
+            return self._wait(idx, timeout)
+        with span.phase("fr_wait", entry=self.plan.entries[idx].name):
+            self._wait(idx, timeout)
+
+    def _wait(self, idx: int, timeout: Optional[float]):
         e = self.entries[idx]
         with e.cond:
             e.wait_count += 1

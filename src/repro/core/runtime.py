@@ -113,9 +113,13 @@ class Runtime:
                  cold_start_cost: float = 0.0,
                  clock: Callable[[], float] = time.monotonic,
                  backend: Optional["InstanceBackend"] = None,
-                 process_boot_fraction: float = 0.8):
+                 process_boot_fraction: float = 0.8,
+                 tracer: Optional["Tracer"] = None):
         self.spec = spec
         self.clock = clock
+        # the fabric's tracer, for the spans of this instance's plan
+        # entries (freshen threads have no invocation span to reach it by)
+        self.tracer = tracer
         self.scope: Dict[str, Any] = {}            # runtime-scoped variables
         self.cache = FreshenCache()
         self.warmth = WarmthLevel.COLD

@@ -231,7 +231,8 @@ class FreshenScheduler:
 
         def factory() -> Runtime:
             rt = Runtime(spec, cold_start_cost=cfg.cold_start_cost,
-                         backend=make_backend(cfg.backend))
+                         backend=make_backend(cfg.backend),
+                         tracer=self.tracer)
             self._join_scope(rt, scope_group)
             return rt
 

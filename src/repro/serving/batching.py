@@ -203,7 +203,10 @@ class EndpointBatcher:
     ``run_batch(payloads: list) -> Future[list]`` dispatches one batch as
     a single pooled invocation through the platform (one acquire/release,
     one traced span — ``ServingEngine`` builds the closure) and resolves
-    to the per-payload results in order.
+    to the per-payload results in order.  With ``submit_times`` (the
+    engine sets it when tracing) it is called as ``run_batch(payloads,
+    submitted)``, ``submitted`` the rows' ``Request.submitted_at``, for
+    the span's ``batch_wait`` phase.
 
     The batcher is *fabric-aware* through two read-only signals:
 
@@ -228,9 +231,11 @@ class EndpointBatcher:
                  batch_size: int, max_wait: float = 0.01,
                  capacity: Optional[Callable[[], int]] = None,
                  retry_interval: float = 0.005,
-                 clock: Callable[[], float] = time.monotonic):
+                 clock: Callable[[], float] = time.monotonic,
+                 submit_times: bool = False):
         self.name = name
         self.run_batch = run_batch
+        self.submit_times = submit_times
         self.batch_size = batch_size
         self.max_wait = max_wait
         self.capacity = capacity
@@ -309,8 +314,10 @@ class EndpointBatcher:
 
     # -- dispatch + backpressure ----------------------------------------
     def _dispatch(self, batch: List[Request]):
+        payloads = [r.payload for r in batch]
         try:
-            fut = self.run_batch([r.payload for r in batch])
+            fut = (self.run_batch(payloads, [r.submitted_at for r in batch])
+                   if self.submit_times else self.run_batch(payloads))
         except PoolSaturated:
             self._backpressure(batch)
             return

@@ -108,8 +108,7 @@ class ModelEndpoint:
         return FreshenPlan(entries)
 
     def _load_weights(self):
-        params, real, modeled = self.store.load(self.name)
-        return params
+        return self.store.load(self.name)
 
     def _compile(self):
         compiled, dt = self.executor.compile(
@@ -221,6 +220,22 @@ class ModelEndpoint:
     def spec(self) -> FunctionSpec:
         return FunctionSpec(self.name, self.code,
                             plan_factory=self.build_plan, app=self.app)
+
+
+def batch_span(tracer, fn: str, app: str, fill: int,
+               submitted: Optional[List[float]] = None):
+    """The invocation span of one batch.  Given the rows' submit times it
+    opens at the oldest, and its ``batch_wait`` phase runs from there to
+    now, the dispatch, with each row's own wait in ``waits``."""
+    if not submitted:
+        return tracer.invocation(fn, app=app, batch=True, fill=fill)
+    oldest = min(submitted)
+    span = tracer.invocation(fn, app=app, start=oldest, batch=True,
+                             fill=fill)
+    wait = span.phase_from("batch_wait", oldest)
+    if wait is not None:
+        wait.annotate(waits=[wait.end - t for t in submitted])
+    return span
 
 
 class ServingEngine:
@@ -437,7 +452,8 @@ class ServingEngine:
                          max_wait: float):
         fill_cap = max(1, min(batch_size, ep.batch_size))
 
-        def run_batch(payloads: List[Any]) -> Future:
+        def run_batch(payloads: List[Any],
+                      submitted: Optional[List[float]] = None) -> Future:
             # one pooled invocation for the whole batch: pad the rows to
             # the endpoint's compiled shape, slice per-request logits
             # rows back out when it resolves
@@ -446,8 +462,8 @@ class ServingEngine:
                                ep.batch_size)
             target = self._target(ep.name)
             if target is self.scheduler:
-                span = self.tracer.invocation(ep.name, app=ep.app,
-                                              batch=True, fill=fill)
+                span = batch_span(self.tracer, ep.name, ep.app, fill,
+                                  submitted)
                 inner = self.scheduler.submit(ep.name, {"tokens": tokens},
                                               _span=span)
             else:                        # cluster routing opens its own span
@@ -467,7 +483,8 @@ class ServingEngine:
 
         self.batchers[ep.name] = EndpointBatcher(
             ep.name, run_batch, batch_size=fill_cap, max_wait=max_wait,
-            capacity=lambda: self._idle_capacity(ep.name))
+            capacity=lambda: self._idle_capacity(ep.name),
+            submit_times=self.tracer.enabled)
 
     def submit_request(self, name: str, tokens_row) -> "Future":
         """Admit ONE request (a single token row of the endpoint's

@@ -1,27 +1,29 @@
 """Versioned weight store: "downloading the ML model from a server", the
 paper's canonical redundant overhead.  Weights are real .npz checkpoints on
-disk (repro.checkpoint); loading measures real IO + deserialization time,
-plus the modeled tier transfer when the store sits behind a datastore tier.
+disk (repro.checkpoint); a load reads one into host memory and uploads it
+to the device leaf by leaf.
+
+Inside a traced freshen-plan entry (``repro.telemetry.current_plan``) a
+load times its two parts as the entry's children: ``read`` (the ``.npz``
+read and the dtype views) and ``upload`` (from the first leaf's transfer
+until every leaf is on the device, with the leaves' ``bytes``).
 """
 from __future__ import annotations
-# fabriclint: allow-file[clock] -- weight-load seconds are measured
-# wall-clock costs fed to the freshen planner.
 
 import os
+import queue
 import threading
-import time
-from typing import Any, Optional, Tuple
+from typing import Any
 
 import jax
 
-from repro.checkpoint import load_metadata, load_pytree, save_pytree
-from repro.core.network import TIERS, Connection
+from repro.checkpoint import place_flat, read_flat, save_pytree
+from repro.telemetry import NULL_SPAN, current_plan
 
 
 class WeightStore:
-    def __init__(self, root: str, tier: str = "edge"):
+    def __init__(self, root: str):
         self.root = root
-        self.tier = TIERS[tier]
         os.makedirs(root, exist_ok=True)
         self._versions: dict[str, int] = {}
         self._templates: dict[str, Any] = {}
@@ -47,20 +49,50 @@ class WeightStore:
         with self._lock:
             return self._versions.get(name, 0)
 
-    def load(self, name: str, conn: Optional[Connection] = None
-             ) -> Tuple[Any, float, float]:
-        """Returns (params, real_seconds, modeled_transfer_seconds)."""
-        t0 = time.monotonic()
+    def load(self, name: str) -> Any:
+        """The params of ``name``'s current version, their uploads issued
+        (the leaves may still be in flight to the device)."""
         with self._lock:
             template = self._templates[name]
-        params = load_pytree(self._path(name), template)
-        real = time.monotonic() - t0
-        nbytes = os.path.getsize(self._path(name))
-        conn = conn or Connection(self.tier)
-        modeled = conn.transfer(nbytes)
+        plan = current_plan() or NULL_SPAN
+        with plan.child("read"):
+            flat = read_flat(self._path(name))
+        upload = plan.open_child("upload")
+        if upload is None:
+            params = place_flat(flat, template)
+        else:
+            params = _timed_upload(plan, upload, flat, template)
         with self._lock:
             self.load_count += 1
-        return params, real, modeled
+        return params
 
     def nbytes(self, name: str) -> int:
         return os.path.getsize(self._path(name))
+
+
+def _timed_upload(plan, upload, flat, template):
+    """``place_flat`` with the ``upload`` child closed when every leaf is
+    on the device.  A thread started here waits for that, so the caller
+    returns as soon as the transfers are issued, as it does untraced, and
+    the entries after it still overlap the upload."""
+    handoff: queue.SimpleQueue = queue.SimpleQueue()
+
+    def wait_ready():
+        try:
+            with plan.annotation(upload.name):
+                params = handoff.get()
+                if params is not None:
+                    jax.block_until_ready(params)
+        finally:
+            plan.close_child(upload)
+
+    threading.Thread(target=wait_ready, daemon=True,
+                     name=f"upload-ready-{plan.fn}").start()
+    params = None
+    try:
+        params = place_flat(flat, template)
+        upload.annotate(bytes=sum(int(leaf.nbytes)
+                                  for leaf in jax.tree.leaves(params)))
+    finally:
+        handoff.put(params)
+    return params

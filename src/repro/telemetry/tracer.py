@@ -6,11 +6,16 @@ request.  Every admitted invocation produces one ``InvocationSpan`` with
 phase children drawn from a fixed taxonomy —
 
     route         placement / prediction+freshen-dispatch overhead
+    batch_wait    a batched invocation's rows waiting in the endpoint
+                  batcher, oldest row's submit to dispatch (per-row
+                  waits in ``waits``)
     queue         admission-to-start hop (router executor queueing)
     acquire       InstancePool.acquire (includes pool queue wait)
     boot_process  sandbox/interpreter boot share of a cold start
     boot_init     init_fn/plan share of a cold start
     warm_to       explicit warmth promotion on the critical path
+    fr_wait       blocked in FrWait on a plan entry a freshen is still
+                  running (the entry in ``entry``)
     run           the run hook proper
     release       InstancePool.release
 
@@ -22,7 +27,21 @@ prediction time, anchored at the *predicted* arrival
 to that invocation, nearest-anchor-within-horizon, the same rule
 ``Accountant._resolve_pending_locked`` bills by), ``expired`` (no
 arrival within the horizon), or ``gated`` (the accounting gate refused
-the dispatch).
+the dispatch).  Every execution of a freshen-plan entry produces one
+``PlanSpan`` — by the freshen hook or inline on an invocation's critical
+path — whose children time the entry's parts (the ``weights`` entry's
+``read`` and ``upload``).
+
+With the tracer enabled, every span or phase that is host *activity* —
+the with-block phases, plan entries and their children — also opens a
+``jax.profiler.TraceAnnotation`` named ``fabric.<name>`` on its own
+thread (``fabric.run``, ``fabric.fr_wait``, ``fabric.plan.weights``,
+``fabric.plan.weights.upload``), so a profiler trace shows it on the
+host plane beside the device ops, on the profiler's clock.  Retroactive
+phases (``queue``, ``batch_wait``) are states, not activity, and stay in
+the rings.  The annotation comes from an already-imported ``jax``: this
+module imports nothing outside the stdlib, and without a profiler
+session an annotation costs about a microsecond.
 
 Design constraints, in order:
 
@@ -34,7 +53,7 @@ Design constraints, in order:
 * **Lock-cheap when enabled.**  A span is mutated only by the thread
   driving its invocation; the tracer's lock is taken once per span
   *completion* (ring-buffer append + freshen matching), never per
-  phase.
+  phase (a plan span's ``open_child`` takes it once more).
 * **Bounded.**  Completed spans live in ``deque(maxlen=capacity)`` ring
   buffers — a long-running platform traces forever without growing.
 * **Deterministic under test.**  ``clock`` is injectable
@@ -50,14 +69,15 @@ from __future__ import annotations
 
 import itertools
 import json
+import sys
 import threading
 import time
 from collections import deque
 from typing import Any, Callable, Dict, List, Optional
 
 #: the fixed phase taxonomy (docs/architecture.md "Observability")
-PHASES = ("route", "queue", "acquire", "boot_process", "boot_init",
-          "warm_to", "run", "release")
+PHASES = ("route", "batch_wait", "queue", "acquire", "boot_process",
+          "boot_init", "warm_to", "fr_wait", "run", "release")
 
 _tls = threading.local()
 
@@ -68,6 +88,12 @@ def current_span() -> Optional["InvocationSpan"]:
     whatever invocation is driving them; background threads (freshen,
     daemon sweeps) see None and skip."""
     return getattr(_tls, "span", None)
+
+
+def current_plan() -> Optional["PlanSpan"]:
+    """The plan-entry span active on this thread, or None: the entry's
+    own work (``WeightStore.load``) times its parts as its children."""
+    return getattr(_tls, "plan", None)
 
 
 class _NullCtx:
@@ -86,6 +112,21 @@ class _NullCtx:
 
 
 _NULL_CTX = _NullCtx()
+
+_trace_annotation = None
+
+
+def _annotation(name: str):
+    """``jax.profiler.TraceAnnotation("fabric.<name>")`` when the process
+    has imported ``jax`` (no profiler session can exist otherwise), else
+    the shared no-op context."""
+    global _trace_annotation
+    if _trace_annotation is None:
+        profiler = sys.modules.get("jax.profiler")
+        if profiler is None:
+            return _NULL_CTX
+        _trace_annotation = profiler.TraceAnnotation
+    return _trace_annotation("fabric." + name)
 
 
 class _NullSpan:
@@ -123,6 +164,13 @@ class _NullSpan:
     def dispatch_done(self):
         return self
 
+    # plan-span compat
+    def child(self, name: str, **attrs):
+        return _NULL_CTX
+
+    def open_child(self, name: str, **attrs):
+        return None
+
     def __bool__(self):
         return False
 
@@ -157,20 +205,24 @@ class PhaseSpan:
 
 class _PhaseCtx:
     """Context manager closing one phase (records end on exit, even on
-    error — a raising run hook still yields a complete span tree)."""
-    __slots__ = ("_span", "_phase")
+    error — a raising run hook still yields a complete span tree), with
+    the phase's profiler annotation open inside it."""
+    __slots__ = ("_tracer", "_phase", "_ann")
 
-    def __init__(self, span: "InvocationSpan", phase: PhaseSpan):
-        self._span = span
+    def __init__(self, tracer: "Tracer", phase: PhaseSpan, label: str):
+        self._tracer = tracer
         self._phase = phase
+        self._ann = _annotation(label)
 
     def __enter__(self):
+        self._ann.__enter__()
         return self._phase
 
     def __exit__(self, exc_type, exc, tb):
-        self._phase.end = self._span.tracer.clock()
+        self._phase.end = self._tracer.clock()
         if exc_type is not None:
             self._phase.attrs["error"] = exc_type.__name__
+        self._ann.__exit__(exc_type, exc, tb)
         return False
 
 
@@ -204,12 +256,13 @@ class InvocationSpan:
     enabled = True
 
     def __init__(self, tracer: "Tracer", span_id: int, fn: str,
-                 app: str = "default", **attrs):
+                 app: str = "default", start: Optional[float] = None,
+                 **attrs):
         self.tracer = tracer
         self.span_id = span_id
         self.fn = fn
         self.app = app
-        self.start = tracer.clock()
+        self.start = tracer.clock() if start is None else start
         self.end: Optional[float] = None
         self.submitted_at: Optional[float] = None
         self.attrs: Dict[str, Any] = dict(attrs)
@@ -223,12 +276,13 @@ class InvocationSpan:
         """Open one phase child; close it by exiting the context."""
         ph = PhaseSpan(name, self.tracer.clock(), attrs or None)
         self.phases.append(ph)
-        return _PhaseCtx(self, ph)
+        return _PhaseCtx(self.tracer, ph, name)
 
     def phase_from(self, name: str, start: float, **attrs
                    ) -> PhaseSpan:
         """Record an already-elapsed phase retroactively (e.g. the
-        ``queue`` hop between submit and invoke start)."""
+        ``queue`` hop between submit and invoke start).  A state, not
+        host activity: no profiler annotation."""
         ph = PhaseSpan(name, start, attrs or None)
         ph.end = self.tracer.clock()
         self.phases.append(ph)
@@ -356,6 +410,119 @@ class FreshenSpan:
                 "linked_invocation": self.linked_invocation}
 
 
+class _ActivePlanCtx:
+    """Thread-local activation of a plan-entry span (``current_plan()``)
+    with its ``fabric.plan.<entry>`` annotation open inside it."""
+    __slots__ = ("_span", "_prev", "_ann")
+
+    def __init__(self, span: "PlanSpan"):
+        self._span = span
+        self._prev = None
+        self._ann = _annotation(f"plan.{span.entry}")
+
+    def __enter__(self):
+        self._prev = getattr(_tls, "plan", None)
+        _tls.plan = self._span
+        self._ann.__enter__()
+        return self._span
+
+    def __exit__(self, *exc):
+        self._ann.__exit__(*exc)
+        _tls.plan = self._prev
+        return False
+
+
+class PlanSpan:
+    """One execution of one freshen-plan entry (``FreshenState._execute``):
+    by the freshen hook (``by="freshen"``) or inline from FrFetch/FrWarm
+    (``by="inline"``); ``invocation`` is the id of the invocation whose
+    thread ran it, if any.  Children time the entry's parts.  A child
+    opened with ``open_child`` may close on another thread after the
+    entry returned (the weights upload, stamped when the leaves are on
+    the device): the span is published once the entry and all of its
+    children have closed."""
+    __slots__ = ("tracer", "span_id", "fn", "entry", "by", "invocation",
+                 "start", "end", "attrs", "children", "_open")
+    enabled = True
+
+    def __init__(self, tracer: "Tracer", span_id: int, fn: str, entry: str,
+                 by: str, invocation: Optional[int] = None):
+        self.tracer = tracer
+        self.span_id = span_id
+        self.fn = fn
+        self.entry = entry
+        self.by = by
+        self.invocation = invocation
+        self.start = tracer.clock()
+        self.end: Optional[float] = None
+        self.attrs: Dict[str, Any] = {}
+        self.children: List[PhaseSpan] = []
+        self._open = 1            # the entry itself + unclosed open_child()s
+
+    def active(self) -> _ActivePlanCtx:
+        """Make this span the thread's ``current_plan()`` for a block."""
+        return _ActivePlanCtx(self)
+
+    def annotation(self, child: str):
+        """The profiler annotation of child ``child``, for a thread that
+        times the child itself."""
+        return _annotation(f"plan.{self.entry}.{child}")
+
+    def child(self, name: str, **attrs) -> _PhaseCtx:
+        """Time one part of the entry on this thread; close by exiting."""
+        ph = PhaseSpan(name, self.tracer.clock(), attrs or None)
+        self.children.append(ph)
+        return _PhaseCtx(self.tracer, ph, f"plan.{self.entry}.{name}")
+
+    def open_child(self, name: str, **attrs) -> PhaseSpan:
+        """Start a child that ``close_child`` ends, from any thread."""
+        ph = PhaseSpan(name, self.tracer.clock(), attrs or None)
+        self.children.append(ph)
+        self.tracer._plan_hold(self)
+        return ph
+
+    def close_child(self, child: PhaseSpan) -> "PlanSpan":
+        child.end = self.tracer.clock()
+        self.tracer._plan_release(self)
+        return self
+
+    def finish(self, error: Optional[str] = None) -> "PlanSpan":
+        """The entry returned (or raised ``error``)."""
+        if self.end is not None:
+            return self
+        self.end = self.tracer.clock()
+        if error is not None:
+            self.attrs["error"] = error
+        self.tracer._plan_release(self)
+        return self
+
+    @property
+    def duration(self) -> float:
+        return (self.end - self.start) if self.end is not None else 0.0
+
+    def child_seconds(self) -> Dict[str, float]:
+        """Summed duration per child name."""
+        out: Dict[str, float] = {}
+        for ph in self.children:
+            out[ph.name] = out.get(ph.name, 0.0) + ph.duration
+        return out
+
+    def complete(self) -> bool:
+        """The entry closed, and every child closed after it began (an
+        ``open_child`` may outlast the entry)."""
+        return self.end is not None and all(
+            ph.end is not None and ph.start >= self.start - 1e-9
+            and ph.end >= ph.start for ph in self.children)
+
+    def to_dict(self) -> dict:
+        return {"kind": "plan", "id": self.span_id, "fn": self.fn,
+                "entry": self.entry, "by": self.by,
+                "invocation": self.invocation, "start": self.start,
+                "end": self.end, "duration": self.duration,
+                "attrs": dict(self.attrs),
+                "children": [ph.to_dict() for ph in self.children]}
+
+
 class Tracer:
     """The span source and sink: hands out spans, matches freshens to
     the arrivals they anchored, and keeps the last ``capacity`` of each
@@ -377,17 +544,22 @@ class Tracer:
         self._ids = itertools.count(1)
         self._spans: deque = deque(maxlen=capacity)       # InvocationSpan
         self._freshens: deque = deque(maxlen=capacity)    # terminal FreshenSpan
+        self._plans: deque = deque(maxlen=capacity)       # closed PlanSpan
         self._pending_freshen: Dict[str, List[FreshenSpan]] = {}
         self.dropped = 0          # completed spans evicted by the ring
 
     # -- span construction ---------------------------------------------
-    def invocation(self, fn: str, app: str = "default", **attrs):
-        """Open one invocation span (``NULL_SPAN`` when disabled)."""
+    def invocation(self, fn: str, app: str = "default",
+                   start: Optional[float] = None, **attrs):
+        """Open one invocation span (``NULL_SPAN`` when disabled).
+        ``start`` backdates the envelope (a batch opens at its oldest
+        row's submit)."""
         if not self.enabled:
             return NULL_SPAN
         with self._lock:
             span_id = next(self._ids)
-        return InvocationSpan(self, span_id, fn, app=app, **attrs)
+        return InvocationSpan(self, span_id, fn, app=app, start=start,
+                              **attrs)
 
     def freshen(self, fn: str, confidence: float = 0.0,
                 level: str = "hot", expected_delay: float = 0.0):
@@ -400,7 +572,32 @@ class Tracer:
         return FreshenSpan(self, span_id, fn, confidence=confidence,
                            level=level, expected_delay=expected_delay)
 
+    def plan(self, fn: str, entry: str, by: str):
+        """Open one plan-entry span (``NULL_SPAN`` when disabled), linked
+        to the invocation active on this thread, if any.  Close it with
+        ``finish``."""
+        if not self.enabled:
+            return NULL_SPAN
+        inv = current_span()
+        with self._lock:
+            span_id = next(self._ids)
+        return PlanSpan(self, span_id, fn, entry, by,
+                        invocation=inv.span_id if inv is not None else None)
+
     # -- lifecycle plumbing (called by spans) ---------------------------
+    def _plan_hold(self, span: PlanSpan):
+        with self._lock:
+            span._open += 1
+
+    def _plan_release(self, span: PlanSpan):
+        with self._lock:
+            span._open -= 1
+            if span._open:
+                return
+            if len(self._plans) == self._plans.maxlen:
+                self.dropped += 1
+            self._plans.append(span)
+
     def _track_freshen(self, span: FreshenSpan):
         with self._lock:
             self._pending_freshen.setdefault(span.fn, []).append(span)
@@ -494,6 +691,11 @@ class Tracer:
                     out.extend(pend)
         return out
 
+    def plan_spans(self) -> List[PlanSpan]:
+        """Closed plan-entry spans, oldest first (ring snapshot)."""
+        with self._lock:
+            return list(self._plans)
+
     def pending_freshens(self) -> int:
         with self._lock:
             return sum(len(v) for v in self._pending_freshen.values())
@@ -502,14 +704,17 @@ class Tracer:
         with self._lock:
             self._spans.clear()
             self._freshens.clear()
+            self._plans.clear()
             self._pending_freshen.clear()
             self.dropped = 0
 
     def snapshot(self) -> dict:
-        """Plain-dict dump for benchmarks: every completed span tree plus
-        per-phase aggregate seconds (sum/count per phase name)."""
+        """Plain-dict dump for benchmarks: every completed span tree and
+        plan-entry span, plus per-phase aggregate seconds (sum/count per
+        phase name)."""
         spans = self.spans()
         freshens = self.freshen_spans()
+        plans = self.plan_spans()
         agg: Dict[str, List[float]] = {}
         for sp in spans:
             for name, secs in sp.phase_seconds().items():
@@ -520,6 +725,7 @@ class Tracer:
         return {
             "invocations": [sp.to_dict() for sp in spans],
             "freshens": [fs.to_dict() for fs in freshens],
+            "plans": [ps.to_dict() for ps in plans],
             "phase_totals": {name: {"seconds": sum(v), "count": len(v),
                                     "mean": sum(v) / len(v)}
                              for name, v in agg.items()},

@@ -82,8 +82,16 @@ def test_disabled_tracer_is_null_and_allocation_free(fake_clock):
         pass
     span.mark_submitted().annotate(x=1).finish()
     NULL_SPAN.dispatched().gated().dispatch_done()
+    # plan-entry spans: the same null span, its children shared constants
+    plan = tr.plan("f", "weights", "freshen")
+    assert plan is NULL_SPAN and tr.invocation("f", start=1.0) is NULL_SPAN
+    assert plan.child("read") is plan.active() is span.phase("run")
+    assert plan.open_child("upload") is None
+    plan.finish(error="x")
     assert tr.spans() == [] and tr.freshen_spans() == []
+    assert tr.plan_spans() == []
     assert NULL_TRACER.invocation("g") is NULL_SPAN
+    assert NULL_TRACER.plan("g", "weights", "inline") is NULL_SPAN
 
 
 def test_active_span_is_thread_local_and_nests(fake_clock):
