@@ -49,7 +49,7 @@ if __name__ == "__main__":
         model,
         OptimizerConfig(peak_lr=1e-3, warmup_steps=20, total_steps=args.steps),
         TrainerConfig(steps=args.steps, checkpoint_every=max(10, args.steps // 3),
-                      checkpoint_path=os.path.join(ckpt_dir, "ck.npz"),
+                      checkpoint_path=os.path.join(ckpt_dir, "ck.ckpt"),
                       num_microbatches=2),
         data)
     hist = trainer.run()

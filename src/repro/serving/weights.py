@@ -1,11 +1,13 @@
 """Versioned weight store: "downloading the ML model from a server", the
-paper's canonical redundant overhead.  Weights are real .npz checkpoints on
-disk (repro.checkpoint); a load reads one into host memory and uploads it
-to the device leaf by leaf.
+paper's canonical redundant overhead.  Weights are real checkpoints on disk
+(repro.checkpoint, one ``.ckpt`` file per name); a load maps the current
+version's file read-only and uploads it to the device leaf by leaf, each
+leaf straight from its view of the mapping.  Publishing replaces the file
+atomically, so a load that races a publish maps one version or the other.
 
 Inside a traced freshen-plan entry (``repro.telemetry.current_plan``) a
-load times its two parts as the entry's children: ``read`` (the ``.npz``
-read and the dtype views) and ``upload`` (from the first leaf's transfer
+load times its two parts as the entry's children: ``read`` (mapping the
+file and viewing its leaves) and ``upload`` (from the first leaf's transfer
 until every leaf is on the device, with the leaves' ``bytes``).
 """
 from __future__ import annotations
@@ -31,7 +33,7 @@ class WeightStore:
         self.load_count = 0
 
     def _path(self, name: str) -> str:
-        return os.path.join(self.root, f"{name}.npz")
+        return os.path.join(self.root, f"{name}.ckpt")
 
     # ------------------------------------------------------------------
     def publish(self, name: str, params) -> int:

@@ -103,7 +103,7 @@ def test_checkpoint_roundtrip(tmp_path):
     cfg = dataclasses.replace(get_config("xlstm-350m").reduced(d_model=128))
     model = make_model(cfg)
     params = model.init(jax.random.PRNGKey(0))
-    path = str(tmp_path / "ck.npz")
+    path = str(tmp_path / "ck.ckpt")
     save_pytree(path, params, metadata={"step": 7})
     like = jax.tree.map(lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype),
                         params)
