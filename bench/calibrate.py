@@ -5,8 +5,8 @@
 
 Each seed is a whole run of the cell (set-up, window, check).  Beside the
 program's reading of each compared number it prints the control's: the
-plain reference computed in float8 (``bench/reference.py``) on the same
-prompts and weights.  ``--keep-trace`` writes the first seconds of a traced
+plain reference of the configuration's architecture module
+(``bench/archs``) computed in float8 on the same prompts and weights.  ``--keep-trace`` writes the first seconds of a traced
 run's trace, reduced to the events ``bench/trace.py`` reads.
 """
 import argparse

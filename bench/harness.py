@@ -8,7 +8,10 @@ out where the mix asks for it); drive the window through
 ``ServingEngine.submit_request`` from the mix's schedule or closed loop;
 wait for every request due in the window; read the device's memory peak;
 free the program's state; check the served logits of the sampled requests
-against the plain reference; print the metrics.
+against the plain reference; print the metrics.  What belongs to one
+family of architectures (the served ``ModelConfig``, the weights, the
+reference, the operation and byte counts) comes from the configuration's
+module in ``bench/archs`` (``Bench.arch``).
 
 End-to-end metrics (``--trace 0``) are taken on the client's side with the
 program's tracer off.  A traced run (``--trace 1``) turns the tracer on,
@@ -35,9 +38,8 @@ from typing import Dict, List, Optional
 
 import numpy as np
 
-from bench import counts, model_config, peaks, reference, traffic
+from bench import model_config, peaks, traffic
 from bench import trace as trace_mod
-from bench.weights import make_params
 
 ROOT = Path(__file__).resolve().parents[1]
 RESULT_GRACE_S = 60.0          # a request due in the window may finish late
@@ -64,7 +66,8 @@ class Bench:
 
     def config_file(self, name: str) -> dict:
         entry = next(c for c in self.spec["configs"] if c["name"] == name)
-        return model_config.load_file(self.root / entry["file"])
+        return model_config.load_file(self.root / entry["file"],
+                                      self.root / "bench" / "archs")
 
     def mix(self, name: str) -> dict:
         return traffic.load(self.root / "bench" / "traffic" / f"{name}.json")
@@ -86,14 +89,23 @@ class Bench:
         """The ``read(run)`` of ``metric``: ``bench/metrics/<stem>.py`` for
         a per-layer metric, ``bench/e2e/<stem>.py`` for an end-to-end one,
         where a name ``a.b`` has the stem ``a``."""
-        stem = metric.split(".")[0]
-        kind = "metrics" if per_layer else "e2e"
+        return self._module("metrics" if per_layer else "e2e",
+                            metric.split(".")[0]).read
+
+    def arch(self, config: str):
+        """The module of ``config``'s architecture family,
+        ``bench/archs/<arch>.py`` where ``<arch>`` is the configuration
+        file's ``arch``: ``build``, ``sizes_of``, ``param_count``,
+        ``make_params``, ``reference_logits`` and ``score_step``."""
+        return self._module("archs", self.config_file(config)["arch"])
+
+    def _module(self, kind: str, stem: str):
         path = self.root / "bench" / kind / f"{stem}.py"
         spec = importlib.util.spec_from_file_location(
             f"bench_{kind}_{stem}", path)
         mod = importlib.util.module_from_spec(spec)
         spec.loader.exec_module(mod)
-        return mod.read
+        return mod
 
 
 # ---------------------------------------------------------------------------
@@ -236,8 +248,9 @@ def run_cell(bench: Bench, cell: str, seed: int, seconds: float,
     devs = _devices(w["chips"], require_tpu)
     compiles = _compile_counter()
     cfile = bench.config_file(w["config"])
-    cfg = model_config.build(cfile)
-    sizes = reference.sizes_of(cfile)
+    arch = bench.arch(w["config"])
+    cfg = arch.build(cfile)
+    sizes = arch.sizes_of(cfile)
     mix = bench.mix(w["traffic"])
     limits = bench.limits(cell)
     names = traffic.endpoints(mix)
@@ -250,7 +263,7 @@ def run_cell(bench: Bench, cell: str, seed: int, seconds: float,
     tmp = tempfile.TemporaryDirectory(prefix="bench-weights-")
     store = WeightStore(tmp.name)
     for i, name in enumerate(names):
-        params = make_params(cfg, seed, i)
+        params = arch.make_params(cfg, seed, i)
         store.publish(name, params)
         del params
     engine = ServingEngine(tracer=Tracer(capacity=1 << 16) if trace else None)
@@ -430,13 +443,13 @@ def run_cell(bench: Bench, cell: str, seed: int, seconds: float,
     for i, name in enumerate(names):
         if name not in by_ep:
             continue
-        params = make_params(cfg, seed, i)
+        params = arch.make_params(cfg, seed, i)
         toks = np.stack([p for p, _ in by_ep[name]])
         served = np.stack([row for _, row in by_ep[name]])
-        ref = reference.reference_logits(sizes, params, toks)
+        ref = arch.reference_logits(sizes, params, toks)
         err = max(err, rel_err(served, ref))
         if control:
-            low = reference.reference_logits(sizes, params, toks, quant="fp8")
+            low = arch.reference_logits(sizes, params, toks, quant="fp8")
             ctl = max(ctl, rel_err(low, ref))
         del params
     compared = sum(len(v) for v in by_ep.values())
@@ -452,8 +465,9 @@ def run_cell(bench: Bench, cell: str, seed: int, seconds: float,
               t0=t0, requests=r, ok=ok, lat_ms=lat_ms, first_ms=first_ms,
               timings=timings,
               fills=fills, batch=B, spans=spans, freshens=freshens,
-              trace=summary, step=counts.score_step(sizes, B, S),
-              row_step=counts.score_step(sizes, 1, S),
+              trace=summary, step=arch.score_step(sizes, B, S),
+              row_step=arch.score_step(sizes, 1, S),
+              params=arch.param_count(sizes),
               peak=peaks.peaks(devs[0].device_kind) if trace else None,
               trace_mark=(mark["t"], mark["end"]) if trace else None)
     metrics = {}
@@ -477,6 +491,7 @@ def run_cell(bench: Bench, cell: str, seed: int, seconds: float,
                                   "max": float(np.nanmax(lag))}
     result["full_gc"] = full_gc
     result["compared_rows"] = compared
+    result["params"] = run.params
     result["finished_late"] = int((r["done"] > t_end).sum())
     result["after_idle"] = int(r["idle"].sum())
     if r["idle"].any():

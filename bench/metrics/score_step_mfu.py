@@ -1,7 +1,8 @@
 """score_step_mfu.<cell>: the whole serving path's share of the chip's
 peak: the operations needed by the request rows that finished inside the
-traced window (one row of the score step per stage served,
-``bench/counts.py``), over the window's length times peak bf16 FLOP/s.
+traced window (one row of the score step per stage served, ``run.row_step``
+from the configuration's architecture module), over the window's length
+times peak bf16 FLOP/s.
 Padding, idle time and host overhead all lower it; it bounds every
 kernel's share from above."""
 

@@ -1,8 +1,9 @@
 """score_step_roofline.<cell>: the score step's XLA program (the
 ``jit_score`` module) against its roofline.  The least time the chip could
-take for one step (``bench/counts.py``: the larger of operations over peak
-FLOP/s and bytes over peak bandwidth, both lower bounds), times the runs in
-the traced window, over the module's summed device time there."""
+take for one step (``counts.least_seconds`` of ``run.step``, the counts of
+the configuration's architecture module: the larger of operations over
+peak FLOP/s and bytes over peak bandwidth, both lower bounds), times the
+runs in the traced window, over the module's summed device time there."""
 
 from bench import counts
 

@@ -1,8 +1,8 @@
-"""weights_read_ms.<cell>: reading one endpoint's weights into host
-memory: the ``.npz`` read and the dtype views (``checkpoint/io.py``
-``read_flat``: the ``read`` child of each ``weights`` plan-entry span),
-mean over the window's weight loads, by freshen threads and inline
-alike."""
+"""weights_read_ms.<cell>: making one endpoint's weights readable on the
+host: mapping its checkpoint file (populated up front) and viewing each
+leaf in its dtype (``checkpoint/io.py`` ``read_flat``: the ``read`` child
+of each ``weights`` plan-entry span), mean over the window's weight loads,
+by freshen threads and inline alike."""
 
 from bench import plan_spans
 

@@ -1,8 +1,9 @@
 """weights_upload_gbps.<cell>: the rate at which weight loads put the
 leaves on the device (``checkpoint/io.py`` ``place_flat``: the
 ``upload`` child of each ``weights`` plan-entry span, from the first
-leaf's transfer until every leaf is on the device): the uploads' summed
-``bytes`` over their summed seconds, in 1e9 bytes per second."""
+leaf's transfer, out of the views of the mapped checkpoint file, until
+every leaf is on the device): the uploads' summed ``bytes`` over their
+summed seconds, in 1e9 bytes per second."""
 
 from bench import plan_spans
 

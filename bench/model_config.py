@@ -3,7 +3,10 @@
 
 The file states the model in the key names of the published
 ``config.json``: ``published`` as released, ``config`` as it is run here.
-``reduced`` lists every key where the two differ.  The served
+``reduced`` lists every key where the two differ.  ``arch`` names the
+module of its family of architectures, ``bench/archs/<arch>.py``, which
+builds the served ``ModelConfig`` and holds the reference, the weights and
+the counts.  For the dense family (``bench/archs/dense.py``) the served
 ``ModelConfig`` is the program's registry entry (``registry``) with every
 size replaced by the file's; ``architecture`` holds what the published
 config implies without a key of its own (QKV bias, activation, dtype).
@@ -12,6 +15,7 @@ from __future__ import annotations
 
 import dataclasses
 import json
+import re
 from pathlib import Path
 
 # published config.json key -> ModelConfig field
@@ -28,10 +32,23 @@ HF_FIELDS = {
     "tie_word_embeddings": "tie_embeddings",
 }
 ARCH_FIELDS = ("qkv_bias", "activation", "dtype")
+ARCHS = Path(__file__).resolve().parent / "archs"
+ARCH_NAME = re.compile(r"[A-Za-z0-9_][A-Za-z0-9_-]{0,63}")
 
 
-def load_file(path: Path) -> dict:
+def load_file(path: Path, archs: Path = ARCHS) -> dict:
+    """A configuration file's contents, refused where its ``arch`` names no
+    module in ``archs`` or a key changed from the published config is not
+    in ``reduced``."""
     spec = json.loads(Path(path).read_text())
+    if "arch" not in spec:
+        raise ValueError(f"{path}: no 'arch': name the module of its "
+                         f"architecture family, bench/archs/<arch>.py")
+    arch = spec["arch"]
+    if not (isinstance(arch, str) and ARCH_NAME.fullmatch(arch)
+            and (Path(archs) / f"{arch}.py").is_file()):
+        raise ValueError(f"{path}: 'arch' {arch!r} has no module "
+                         f"<arch>.py in {archs}")
     pub, run = spec["published"], spec["config"]
     changed = sorted(k for k in set(pub) | set(run) if pub.get(k) != run.get(k))
     if changed != sorted(spec["reduced"]):

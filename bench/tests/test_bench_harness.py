@@ -1,9 +1,11 @@
 """The harness on the CPU at reduced widths: the last line of each mix,
-the refusal of a CPU device, and a new cell added as data alone."""
+the refusal of a CPU device, a new cell added as data alone and a new
+family of architectures added as files alone."""
 import os
 
 os.environ.setdefault("JAX_PLATFORMS", "cpu")
 
+import hashlib  # noqa: E402
 import json  # noqa: E402
 import shutil  # noqa: E402
 import subprocess  # noqa: E402
@@ -11,7 +13,7 @@ import sys  # noqa: E402
 
 import pytest  # noqa: E402
 
-from bench.harness import ROOT, Bench  # noqa: E402
+from bench.harness import CHECK, ROOT, Bench  # noqa: E402
 from bench.tests import tiny  # noqa: E402
 from bench.tests.harness_util import fake_device, run  # noqa: E402
 
@@ -107,6 +109,85 @@ def test_new_cell_is_data_alone(tmp_path, monkeypatch):
     fake_device(monkeypatch)
     res = run(root, "tiny-extra.pulse", trace=True)
     assert res["metrics"]["rows_served.pulse"]["value"] == res["attempted"]
+
+
+PROBE = '''"""Dense's architecture family, each call recorded beside this file
+and the reference's logits moved by SHIFT."""
+from pathlib import Path
+
+from bench.archs import dense
+
+SHIFT = {shift}
+CALLS = Path(__file__).with_name("probe.calls")
+
+
+def _recorded(name):
+    def call(*args, **kw):
+        with CALLS.open("a") as f:
+            f.write(name + "\\n")
+        return getattr(dense, name)(*args, **kw)
+    return call
+
+
+build = _recorded("build")
+sizes_of = _recorded("sizes_of")
+param_count = _recorded("param_count")
+make_params = _recorded("make_params")
+score_step = _recorded("score_step")
+
+
+def reference_logits(sizes, params, tokens, quant=None):
+    return _recorded("reference_logits")(sizes, params, tokens, quant) + SHIFT
+'''
+ARCH_NAMES = {"build", "sizes_of", "param_count", "make_params",
+              "reference_logits", "score_step"}
+
+
+def _tree_digest():
+    """Every file of the real benchmark, by content."""
+    files = [ROOT / "BENCHMARK.json"] + sorted(
+        p for p in (ROOT / "bench").rglob("*")
+        if p.is_file() and "__pycache__" not in p.parts)
+    return {str(p): hashlib.sha256(p.read_bytes()).hexdigest()
+            for p in files}
+
+
+def test_new_architecture_is_files_alone(tmp_path):
+    root = tiny.build(tmp_path)
+    b = root / "bench"
+    probe = b / "archs" / "probe.py"
+    probe.write_text(PROBE.format(shift=0.0))
+    spec = dict(tiny.build_spec("tiny-qwen"), name="tiny-probe", arch="probe")
+    (b / "configs" / "tiny-probe.json").write_text(json.dumps(spec))
+    (b / "cells" / "tiny-probe.steady.json").write_text(
+        json.dumps({"limits": {CHECK: 0.05}}))
+    top = json.loads((root / "BENCHMARK.json").read_text())
+    top["configs"].append({"name": "tiny-probe", "source": "test",
+                           "file": "bench/configs/tiny-probe.json",
+                           "reduced": [], "why": "test"})
+    top["workloads"].append({"name": "tiny-probe.steady",
+                             "config": "tiny-probe", "traffic": "steady",
+                             "chips": 1, "why": "test"})
+    for m in top["end_to_end"]:
+        if m["name"] == "latency_p50_ms":
+            m["workloads"].append("tiny-probe.steady")
+    (root / "BENCHMARK.json").write_text(json.dumps(top))
+    before = _tree_digest()
+
+    res = run(root, "tiny-probe.steady")
+    assert res["correct"] and res["failed"] == 0
+    assert set(res["metrics"]) == {"latency_p50_ms", "setup_s"}
+    calls = probe.with_name("probe.calls").read_text().split()
+    assert set(calls) == ARCH_NAMES
+    dense = Bench(root).arch("tiny-qwen")
+    assert res["params"] == dense.param_count(dense.sizes_of(spec))
+    assert _tree_digest() == before
+
+    # the verdict is the module's reference's
+    probe.write_text(PROBE.format(shift=1.0))
+    res = run(root, "tiny-probe.steady")
+    assert res["correct"] is False and res["failed"] == 0
+    assert res["checks"][CHECK]["value"] > res["checks"][CHECK]["limit"]
 
 
 def _command(cwd, env):

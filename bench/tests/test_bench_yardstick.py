@@ -24,7 +24,8 @@ from bench.tests import tiny  # noqa: E402
 from bench.weights import make_params, param_shapes  # noqa: E402
 
 DATA = Path(__file__).parent / "data"
-REAL = ["qwen2-0.5b", "phi3-medium-14b"]
+REAL = [c["name"] for c in
+        json.loads((ROOT / "BENCHMARK.json").read_text())["configs"]]
 
 
 def _spec(name):
@@ -34,10 +35,10 @@ def _spec(name):
 # -- counts ----------------------------------------------------------------
 @pytest.mark.parametrize("name", REAL)
 def test_param_count_matches_program_and_file(name):
-    spec = _spec(name)
-    cfg = model_config.build(spec)
-    z = reference.sizes_of(spec)
-    assert counts.param_count(z) == cfg.param_count() == spec["params"]
+    spec, arch = _spec(name), Bench().arch(name)
+    cfg = arch.build(spec)
+    z = arch.sizes_of(spec)
+    assert arch.param_count(z) == cfg.param_count() == spec["params"]
     leaves = jax.tree.leaves(param_shapes(cfg))
     assert sum(int(np.prod(x.shape)) for x in leaves) == spec["params"]
 
@@ -85,6 +86,20 @@ def test_phi3_file_states_the_published_vocabulary():
     cfg = model_config.build(spec)
     assert (cfg.vocab_size, cfg.n_layers, cfg.norm_eps) == (32064, 8, 1e-5)
     assert spec["reduced"] == ["num_hidden_layers"]
+
+
+@pytest.mark.parametrize("arch, says", [
+    (None, "no 'arch'"), ("moe", "'arch' 'moe' has no module"),
+    ("../configs/qwen2-0.5b", "has no module")])
+def test_config_file_needs_an_arch_module(tmp_path, arch, says):
+    spec = _spec("qwen2-0.5b")
+    del spec["arch"]
+    if arch is not None:
+        spec["arch"] = arch
+    path = tmp_path / "c.json"
+    path.write_text(json.dumps(spec))
+    with pytest.raises(ValueError, match=re.escape(says)):
+        model_config.load_file(path)
 
 
 def test_config_file_refuses_unlisted_change(tmp_path):
@@ -184,22 +199,28 @@ def test_zipf_spreads_the_rate():
 
 
 # -- reference against the program ------------------------------------------
+@pytest.fixture(scope="module")
+def tiny_bench(tmp_path_factory):
+    return Bench(tiny.build(tmp_path_factory.mktemp("bench")))
+
+
 @pytest.mark.parametrize("name", list(tiny.CONFIGS))
-def test_reference_matches_program_forward(name):
+def test_reference_matches_program_forward(tiny_bench, name):
     spec = tiny.build_spec(name, dtype="float32")
-    cfg = model_config.build(spec)
+    arch = tiny_bench.arch(name)
+    cfg = arch.build(spec)
     from repro.models import make_model
     model = make_model(cfg)
-    params = make_params(cfg, 3)
+    params = arch.make_params(cfg, 3)
     toks = np.random.default_rng(0).integers(0, cfg.vocab_size, (3, 16),
                                              dtype=np.int32)
     with jax.default_matmul_precision("highest"):
         x, _ = model.forward(params, jnp.asarray(toks))
         want = np.asarray(model._logits(params, x[:, -1:]))[:, 0]
-    got = reference.reference_logits(reference.sizes_of(spec), params, toks)
+    got = arch.reference_logits(arch.sizes_of(spec), params, toks)
     np.testing.assert_allclose(got, want, rtol=2e-4, atol=2e-4)
-    low = reference.reference_logits(reference.sizes_of(spec), params, toks,
-                                     quant="fp8")
+    low = arch.reference_logits(arch.sizes_of(spec), params, toks,
+                                quant="fp8")
     assert np.abs(low - want).max() > 10 * np.abs(got - want).max()
 
 

@@ -1,6 +1,6 @@
 """A benchmark root at reduced widths for the CPU tests: the real mixes'
 shapes with small models, short keep-alives and low rates, built as data
-beside a copy of the real metric readers."""
+beside a copy of the real metric readers and architecture modules."""
 from __future__ import annotations
 
 import json
@@ -39,7 +39,8 @@ CELLS = [("tiny-qwen.fleet", "tiny-qwen", "fleet"),
 def build_spec(name: str, dtype: str = "bfloat16") -> dict:
     """The configuration file of tiny config ``name``."""
     c = CONFIGS[name]
-    return {"name": name, "registry": c["registry"], "published": c["config"],
+    return {"name": name, "arch": "dense", "registry": c["registry"],
+            "published": c["config"],
             "config": c["config"], "reduced": [],
             "architecture": {"qkv_bias": c["qkv_bias"], "activation": "swiglu",
                              "dtype": dtype}}
@@ -51,8 +52,9 @@ def build(root: Path, limit: float = 0.05) -> Path:
     b = root / "bench"
     for sub in ("configs", "traffic", "cells"):
         (b / sub).mkdir(parents=True, exist_ok=True)
-    for kind in ("metrics", "e2e"):
-        shutil.copytree(ROOT / "bench" / kind, b / kind)
+    for kind in ("metrics", "e2e", "archs"):
+        shutil.copytree(ROOT / "bench" / kind, b / kind,
+                        ignore=shutil.ignore_patterns("__pycache__"))
     configs = []
     for name in CONFIGS:
         path = b / "configs" / f"{name}.json"
