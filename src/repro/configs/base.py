@@ -39,10 +39,38 @@ class MoEConfig:
     router_jitter: float = 0.0
     load_balance_coef: float = 0.01
     # "einsum" = GShard one-hot dispatch (baseline); "gather" = sort-free
-    # take/segment-sum dropless dispatch (beyond-paper perf variant).
+    # take/segment-sum dispatch (beyond-paper perf variant); both drop what
+    # overflows an expert's capacity.  "dropless" = pairs sorted by expert
+    # and one grouped matmul per projection: every routed pair computed.
     dispatch: str = "einsum"
     capacity_factor: float = 1.25
     group_size: int = 512           # tokens per dispatch group
+    # The experts this layer holds, (first, count), of the ``n_experts``
+    # the router scores: one device's share under expert parallelism.
+    # None => all of them.  A share needs the "dropless" dispatch.
+    held: Optional[Tuple[int, int]] = None
+    norm_topk: bool = True          # renormalise the top-k gates to sum 1
+    routed_scale: float = 1.0       # gate multiplier when not renormalised
+
+    @property
+    def held_range(self) -> Tuple[int, int]:
+        return self.held or (0, self.n_experts)
+
+
+@dataclass(frozen=True)
+class YaRNConfig:
+    """YaRN rope scaling (arXiv:2309.00071) in DeepSeek-V2's form: each
+    inverse frequency blends its extrapolated value with the value
+    interpolated by ``factor``, by a linear ramp between the correction
+    dims of ``beta_fast`` and ``beta_slow`` rotations over
+    ``original_max_position``; the softmax scale is multiplied by
+    mscale(``mscale_all_dim``)^2."""
+    factor: float
+    original_max_position: int
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    mscale: float = 1.0
+    mscale_all_dim: float = 0.0
 
 
 @dataclass(frozen=True)
@@ -55,6 +83,7 @@ class MLAConfig:
     # decode path: "naive" re-expands cached latents each step;
     # "absorbed" folds W_UK/W_UV into the query/output projections.
     decode_mode: str = "naive"
+    yarn: Optional[YaRNConfig] = None   # rope scaling of the rope part
 
 
 @dataclass(frozen=True)
@@ -225,7 +254,8 @@ class ModelConfig:
         if kind in ("attn_moe", "mla_moe"):
             e = self.moe
             per_expert = 3 * d * e.d_ff if self.activation == "swiglu" else 2 * d * e.d_ff
-            experts = (e.top_k + e.n_shared) if active_only else (e.n_experts + e.n_shared)
+            held = e.held_range[1]
+            experts = (e.top_k + e.n_shared) if active_only else (held + e.n_shared)
             n += experts * per_expert + d * e.n_experts   # router
         elif kind in ("attn", "attn_local", "mla", "rglru"):
             if self.d_ff:
@@ -264,7 +294,8 @@ class ModelConfig:
         if self.moe:
             kw["moe"] = dataclasses.replace(
                 self.moe, n_experts=4, top_k=2,
-                n_shared=min(1, self.moe.n_shared), d_ff=d_model, group_size=32)
+                n_shared=min(1, self.moe.n_shared), d_ff=d_model, group_size=32,
+                held=None)
         if self.mla:
             kw["mla"] = dataclasses.replace(
                 self.mla, kv_lora_rank=64, nope_head_dim=32, rope_head_dim=16,

@@ -47,10 +47,11 @@ def rope_frequencies(head_dim, theta):
     return 1.0 / (theta ** exponent)                      # (head_dim/2,)
 
 
-def apply_rope(x, positions, theta):
-    """x: (..., S, H, hd); positions: broadcastable to (..., S)."""
+def apply_rope(x, positions, theta, inv_freq=None):
+    """x: (..., S, H, hd); positions: broadcastable to (..., S).
+    ``inv_freq`` (hd/2,) replaces the plain ``theta`` frequencies (YaRN)."""
     hd = x.shape[-1]
-    freqs = rope_frequencies(hd, theta)                   # (hd/2,)
+    freqs = rope_frequencies(hd, theta) if inv_freq is None else inv_freq
     angles = positions[..., None].astype(jnp.float32) * freqs  # (..., S, hd/2)
     angles = angles[..., None, :]                          # (..., S, 1, hd/2)
     cos, sin = jnp.cos(angles), jnp.sin(angles)

@@ -7,6 +7,11 @@ single shared RoPE key head — the paper's memory win.  Two decode paths:
 * ``absorbed`` — fold W_UK into the query and W_UV into the output so decode
   attends directly over latents (DeepSeek's deployment optimization; our
   beyond-paper perf variant for decode shapes).
+
+With ``MLAConfig.yarn`` set, the rope part of q and k uses YaRN's blended
+frequencies and the softmax scale carries mscale(mscale_all_dim)^2, as
+DeepSeek-V2 publishes them; every path (prefill, both decodes) shares
+``rope_inv_freq`` and ``softmax_scale``.
 """
 from __future__ import annotations
 
@@ -17,7 +22,57 @@ import jax.numpy as jnp
 
 from repro.models.layers import (NEG_INF, apply_rope, dense_init, init_rmsnorm,
                                  flash_attention, flash_attention_tri,
-                                 rmsnorm_apply)
+                                 rmsnorm_apply, rope_frequencies)
+
+
+def yarn_mscale(factor: float, mscale: float) -> float:
+    return 1.0 if factor <= 1 else 0.1 * mscale * math.log(factor) + 1.0
+
+
+def yarn_correction_range(yarn, dim: int, theta: float):
+    """The rope dims (of ``dim``) between which YaRN's ramp runs: those
+    that turn ``beta_fast`` and ``beta_slow`` times over the original
+    context, floored and ceiled."""
+    def dim_of(rotations):
+        return (dim * math.log(yarn.original_max_position
+                               / (rotations * 2 * math.pi))
+                / (2 * math.log(theta)))
+    low = math.floor(dim_of(yarn.beta_fast))
+    high = math.ceil(dim_of(yarn.beta_slow))
+    return max(low, 0), min(high, dim - 1)
+
+
+def rope_inv_freq(cfg):
+    """(rope_head_dim / 2,) inverse frequencies of the rope part."""
+    m = cfg.mla
+    extra = rope_frequencies(m.rope_head_dim, cfg.rope_theta)
+    if m.yarn is None:
+        return extra
+    low, high = yarn_correction_range(m.yarn, m.rope_head_dim, cfg.rope_theta)
+    ramp = jnp.clip((jnp.arange(m.rope_head_dim // 2, dtype=jnp.float32)
+                     - low) / max(high - low, 0.001), 0.0, 1.0)
+    return extra / m.yarn.factor * ramp + extra * (1.0 - ramp)
+
+
+def softmax_scale(cfg) -> float:
+    m = cfg.mla
+    scale = 1.0 / math.sqrt(m.nope_head_dim + m.rope_head_dim)
+    if m.yarn is not None and m.yarn.mscale_all_dim:
+        scale *= yarn_mscale(m.yarn.factor, m.yarn.mscale_all_dim) ** 2
+    return scale
+
+
+def _rope(x, positions, cfg):
+    """x: (..., S, H, rope_head_dim) rotated, then scaled by YaRN's
+    mscale(mscale) / mscale(mscale_all_dim) where that is not 1."""
+    out = apply_rope(x, positions, cfg.rope_theta, rope_inv_freq(cfg))
+    y = cfg.mla.yarn
+    if y is not None:
+        gain = (yarn_mscale(y.factor, y.mscale)
+                / yarn_mscale(y.factor, y.mscale_all_dim))
+        if gain != 1.0:
+            out = (out.astype(jnp.float32) * gain).astype(out.dtype)
+    return out
 
 
 def init_mla(key, cfg):
@@ -53,7 +108,7 @@ def _mla_q(p, x, cfg, positions):
         q = x @ p["wq"]
     q = q.reshape(B, S, H, qd)
     qn, qr = q[..., :m.nope_head_dim], q[..., m.nope_head_dim:]
-    qr = apply_rope(qr, positions, cfg.rope_theta)
+    qr = _rope(qr, positions, cfg)
     return qn, qr
 
 
@@ -62,7 +117,7 @@ def _mla_latents(p, x, cfg, positions):
     kv = x @ p["wkv_a"]
     ckv, kr = kv[..., :m.kv_lora_rank], kv[..., m.kv_lora_rank:]
     ckv = rmsnorm_apply(p["ckv_norm"], ckv, cfg.norm_eps)
-    kr = apply_rope(kr[:, :, None, :], positions, cfg.rope_theta)[:, :, 0, :]
+    kr = _rope(kr[:, :, None, :], positions, cfg)[:, :, 0, :]
     return ckv, kr
 
 
@@ -75,8 +130,8 @@ def _expand(p, ckv, cfg):
     return kvb[..., :m.nope_head_dim], kvb[..., m.nope_head_dim:]
 
 
+@jax.named_scope("mla")
 def mla_prefill(p, x, cfg, *, positions=None, use_tri=False):
-    m = cfg.mla
     B, S, _ = x.shape
     if positions is None:
         positions = jnp.arange(S)[None, :]
@@ -86,14 +141,14 @@ def mla_prefill(p, x, cfg, *, positions=None, use_tri=False):
     q = jnp.concatenate([qn, qr], axis=-1)
     k = jnp.concatenate(
         [kn, jnp.broadcast_to(kr[:, :, None, :], qr.shape)], axis=-1)
-    scale = 1.0 / math.sqrt(m.nope_head_dim + m.rope_head_dim)
     fn = flash_attention_tri if use_tri else flash_attention
-    out = fn(q, k, v, causal=True, scale=scale,
+    out = fn(q, k, v, causal=True, scale=softmax_scale(cfg),
              q_chunk=cfg.q_chunk, kv_chunk=cfg.kv_chunk)
     out = out.reshape(B, S, -1) @ p["wo"]
     return out, (ckv, kr)
 
 
+@jax.named_scope("mla")
 def mla_decode(p, x, cfg, cache, pos):
     """cache: {"ckv": (B,S,lora), "kr": (B,S,rope_dim)}; pos: (B,)."""
     m = cfg.mla
@@ -105,7 +160,7 @@ def mla_decode(p, x, cfg, cache, pos):
     ckv = cache["ckv"].at[bidx, pos].set(ckv_t[:, 0])
     kr = cache["kr"].at[bidx, pos].set(kr_t[:, 0])
     S = ckv.shape[1]
-    scale = 1.0 / math.sqrt(m.nope_head_dim + m.rope_head_dim)
+    scale = softmax_scale(cfg)
     valid = jnp.arange(S)[None, :] <= pos[:, None]
 
     if m.decode_mode == "absorbed":

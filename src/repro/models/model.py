@@ -74,19 +74,29 @@ class Model:
 
     # ------------------------------------------------------------------
     def forward(self, params, tokens, frontend_embeds=None,
-                frontend_mask=None):
-        """Full-sequence forward to hidden states; returns (x, aux)."""
+                frontend_mask=None, expert_rows=False):
+        """Full-sequence forward to hidden states; returns (x, aux), and
+        with ``expert_rows`` also the rows routed to each held expert of
+        every expert layer, in layer order: (expert layers, held) int32."""
         cfg = self.cfg
         x = self._embed(params, tokens, frontend_embeds, frontend_mask)
         if self.constrain is not None:
             x = self.constrain(x, "activation")
         aux = jnp.zeros((), jnp.float32)
+        rows = []
         for i, (pattern, repeats) in enumerate(cfg.segments):
-            x, _, a = transformer.segment_scan(
+            x, _, a, *seg_rows = transformer.segment_scan(
                 pattern, repeats, cfg, params[f"seg{i}"], x,
                 use_tri=self.use_tri, remat=cfg.remat,
-                constrain=self.constrain)
+                constrain=self.constrain, expert_rows=expert_rows)
             aux = aux + a
+            held = [r for r in (seg_rows[0] if seg_rows else ())
+                    if r is not None]              # each (repeats, held)
+            if held:
+                rows.append(jnp.stack(held, axis=1).reshape(
+                    -1, held[0].shape[-1]))
+        if expert_rows:
+            return x, aux, jnp.concatenate(rows)
         return x, aux
 
     def loss(self, params, batch, constrain=None, seq_chunk=512):

@@ -7,10 +7,18 @@ two dispatch strategies:
 * ``gather``  — scatter/gather dispatch: tokens are placed into a dense
   (E·C, d) buffer by slot index and combined back by gather.  FLOP-free
   dispatch; the beyond-paper perf variant (see EXPERIMENTS.md §Perf).
+* ``dropless`` — the (token, slot) pairs routed to held experts are sorted
+  by expert into a buffer of the static worst case, T·min(k, held) rows,
+  and each projection is one grouped matmul (``lax.ragged_dot``) over the
+  groups' real sizes: nothing is dropped, whatever the imbalance, and the
+  work follows the routed rows.
 
 Expert weights are stacked on a leading E axis => expert parallelism is a
 sharding rule (experts over the "model"/"expert" mesh axis), and XLA inserts
-the all-to-alls.
+the all-to-alls.  ``MoEConfig.held`` gives a layer one device's share of
+that axis instead: the router still scores all ``n_experts``, the layer
+holds and computes only its own experts, and pairs routed elsewhere add
+nothing here (the shared experts are added for every token).
 """
 from __future__ import annotations
 
@@ -25,10 +33,11 @@ def init_moe(key, cfg):
     d = cfg.d_model
     dtype = jnp.dtype(cfg.dtype)
     ks = jax.random.split(key, 5)
+    held = e.held_range[1]
     def expert_stack(k, in_dim, out_dim):
-        kk = jax.random.split(k, e.n_experts)
+        kk = jax.random.split(k, held)
         return jnp.stack([dense_init(kk[i], in_dim, out_dim, dtype)
-                          for i in range(e.n_experts)])
+                          for i in range(held)])
     # expert weights use distinct names (wi_e/...) so sharding rules can
     # target the expert-stacked 3D layout without colliding with dense MLPs
     p = {"router": dense_init(ks[0], d, e.n_experts, jnp.float32)}
@@ -58,11 +67,17 @@ def _expert_ffn(p, x, activation):
 
 
 def _routing(p, x2d, e):
-    """x2d: (T, d) -> (probs (T,k), idx (T,k), aux_loss scalar)."""
-    logits = (x2d.astype(jnp.float32) @ p["router"])          # (T, E)
+    """x2d: (T, d) -> (probs (T,k), idx (T,k), aux_loss scalar).  The
+    gate runs in float32 (``HIGHEST``: a TPU's default would round the
+    operands to bfloat16 and flip near-tied top-k choices)."""
+    logits = jnp.matmul(x2d.astype(jnp.float32), p["router"],
+                        precision=jax.lax.Precision.HIGHEST)    # (T, E)
     gates = jax.nn.softmax(logits, axis=-1)
     probs, idx = jax.lax.top_k(gates, e.top_k)                # (T, k)
-    probs = probs / jnp.maximum(probs.sum(-1, keepdims=True), 1e-9)
+    if e.norm_topk:
+        probs = probs / jnp.maximum(probs.sum(-1, keepdims=True), 1e-9)
+    elif e.routed_scale != 1.0:
+        probs = probs * e.routed_scale
     # Switch/GShard load-balance aux loss
     me = gates.mean(axis=0)                                   # (E,)
     onehot = jax.nn.one_hot(idx, e.n_experts, dtype=jnp.float32)  # (T,k,E)
@@ -76,13 +91,69 @@ def _capacity(tokens_per_group, e):
     return max(4, -(-c // 4) * 4)                             # round up to 4
 
 
-def moe_apply(p, x, cfg):
-    """x: (B, S, d) -> (out, aux_loss)."""
+def _held_key(idx, e):
+    """(T, k) global expert ids -> each pair's held expert, ``count`` for
+    pairs routed to experts this layer does not hold."""
+    first, count = e.held_range
+    local = idx - first
+    return jnp.where((local >= 0) & (local < count), local, count)
+
+
+def _held_rows(key, count):
+    """Pairs routed to each held expert: (count,) int32."""
+    return jnp.zeros((count + 1,), jnp.int32).at[key.reshape(-1)].add(1)[:count]
+
+
+def _grouped_ffn(p, xs, sizes, activation):
+    """xs: (R, d) rows sorted by expert, ``sizes`` (held,) rows per expert
+    -> (R, d); rows past ``sizes.sum()`` are left undefined."""
+    def mm(a, w):
+        return jax.lax.ragged_dot(a, w, sizes)
+    if activation == "swiglu":
+        h = jax.nn.silu(mm(xs, p["wg_e"])) * mm(xs, p["wi_e"])
+    elif activation == "squared_relu":
+        h = jnp.square(jax.nn.relu(mm(xs, p["wi_e"])))
+    else:
+        h = jax.nn.gelu(mm(xs, p["wi_e"]))
+    return mm(h, p["wo_e"])
+
+
+def _dropless(p, x2d, probs, key, e, activation):
+    """Every pair routed to a held expert, computed: (T, d) routed output."""
+    T, d = x2d.shape
+    k = e.top_k
+    count = e.held_range[1]
+    rows = T * min(k, count)           # a token's k experts are distinct
+    flat = key.reshape(-1)                                     # (T*k,)
+    sizes = _held_rows(key, count)
+    with jax.named_scope("moe.experts"):
+        order = jnp.argsort(flat, stable=True)[:rows]          # by expert
+        ys = _grouped_ffn(p, x2d[order // k], sizes, activation)
+    with jax.named_scope("moe.combine"):
+        pos = jnp.zeros((T * k,), jnp.int32).at[order].set(
+            jnp.arange(rows, dtype=jnp.int32)).reshape(T, k)
+        held = key < count
+        w = jnp.where(held, probs, 0.0)[..., None]
+        picked = jnp.where(held[..., None], ys[pos].astype(jnp.float32), 0.0)
+        return jnp.sum(picked * w, axis=1).astype(x2d.dtype)
+
+
+def moe_apply(p, x, cfg, *, rows=False):
+    """x: (B, S, d) -> (out, aux_loss), and with ``rows`` the pairs routed
+    to each held expert, (held,) int32."""
     e = cfg.moe
     B, S, d = x.shape
     T = B * S
     x2d = x.reshape(T, d)
-    probs, idx, aux = _routing(p, x2d, e)
+    with jax.named_scope("moe.route"):
+        probs, idx, aux = _routing(p, x2d, e)
+        key = _held_key(idx, e)
+    if e.dispatch == "dropless":
+        out = _dropless(p, x2d, probs, key, e, cfg.activation)
+        return _finish(p, x, out.reshape(B, S, d), aux, cfg, key, rows)
+    if e.held is not None:
+        raise ValueError(f"{cfg.name}: a share of the experts "
+                         f"(held={e.held}) needs the 'dropless' dispatch")
 
     gs = min(e.group_size, T)
     assert T % gs == 0, (T, gs)
@@ -131,31 +202,41 @@ def moe_apply(p, x, cfg):
         out2d = jnp.sum(gathered * probs_g[..., None].astype(x.dtype), axis=2)
     else:
         raise ValueError(e.dispatch)
+    return _finish(p, x, out2d.reshape(B, S, d), aux, cfg, key, rows)
 
-    out = out2d.reshape(B, S, d)
+
+def _finish(p, x, out, aux, cfg, key, rows):
+    """Add the shared experts; append the held experts' rows if asked."""
+    e = cfg.moe
     if e.n_shared:
         from repro.models.layers import mlp_apply
         out = out + mlp_apply(p["shared"], x, cfg.activation)
+    if rows:
+        return out, aux, _held_rows(key, e.held_range[1])
     return out, aux
 
 
 def moe_ref(p, x, cfg):
     """Dense oracle: every token through its top-k experts exactly (no
-    capacity drops).  Used in tests to bound dispatch-path error."""
+    capacity drops); of a share (``held``), the held experts' part.  Used
+    in tests to bound dispatch-path error."""
     e = cfg.moe
     B, S, d = x.shape
     x2d = x.reshape(-1, d)
     probs, idx, aux = _routing(p, x2d, e)
+    key = _held_key(idx, e)
+    count = e.held_range[1]
     outs = []
-    for j in range(e.n_experts):
+    for j in range(count):
         xin = x2d[None]                                            # (1,T,d)
         y = _expert_ffn({k: v[j:j + 1] for k, v in p.items()
                          if k in ("wi_e", "wg_e", "wo_e")}, xin,
                         cfg.activation)[0]
         outs.append(y)
-    ys = jnp.stack(outs)                                           # (E,T,d)
+    outs.append(jnp.zeros_like(x2d))                # experts not held here
+    ys = jnp.stack(outs)                                        # (held+1,T,d)
     sel = jnp.take_along_axis(
-        ys.transpose(1, 0, 2), idx[..., None].astype(jnp.int32), axis=1)
+        ys.transpose(1, 0, 2), key[..., None].astype(jnp.int32), axis=1)
     out2d = jnp.sum(sel * probs[..., None].astype(x.dtype), axis=1)
     out = out2d.reshape(B, S, d)
     if e.n_shared:

@@ -54,9 +54,12 @@ def _has_ffn(kind, cfg):
 # ----------------------------------------------------------------------
 # per-block apply
 def block_apply(kind, cfg, p, x, *, cache=None, pos=None, decode=False,
-                use_tri=False):
-    """Returns (x, new_cache_or_None, aux_scalar)."""
+                use_tri=False, expert_rows=False):
+    """Returns (x, new_cache_or_None, aux_scalar); with ``expert_rows``
+    also the rows routed to each held expert, (held,) int32 (None for a
+    block without experts)."""
     aux = jnp.zeros((), jnp.float32)
+    rows = None
     if decode and cache is not None:
         # keep per-layer cache slices opaque: without this barrier XLA:CPU
         # hoists an f32 convert of the ENTIRE stacked cache out of the
@@ -98,12 +101,16 @@ def block_apply(kind, cfg, p, x, *, cache=None, pos=None, decode=False,
     if _has_ffn(kind, cfg):
         h2 = rmsnorm_apply(p["norm2"], x, cfg.norm_eps)
         if kind in ("attn_moe", "mla_moe"):
-            out2, aux = moe.moe_apply(p["ffn"], h2, cfg)
+            out2, aux, *held = moe.moe_apply(p["ffn"], h2, cfg,
+                                             rows=expert_rows)
+            rows = held[0] if held else None
         else:
             out2 = layers.mlp_apply(p["ffn"], h2, cfg.activation)
         if cfg.use_post_norm:
             out2 = rmsnorm_apply(p["post_norm2"], out2, cfg.norm_eps)
         x = x + out2
+    if expert_rows:
+        return x, new_cache, aux, rows
     return x, new_cache, aux
 
 
@@ -171,17 +178,21 @@ def init_segment(key, pattern, repeats, cfg):
 
 
 def superblock_apply(pattern, cfg, params_tuple, x, caches_tuple=None,
-                     pos=None, decode=False, use_tri=False, constrain=None):
-    new_caches, aux_total = [], jnp.zeros((), jnp.float32)
+                     pos=None, decode=False, use_tri=False, constrain=None,
+                     expert_rows=False):
+    new_caches, aux_total, rows = [], jnp.zeros((), jnp.float32), []
     for i, kind in enumerate(pattern):
         cache_i = caches_tuple[i] if caches_tuple is not None else None
-        x, nc, aux = block_apply(kind, cfg, params_tuple[i], x,
-                                 cache=cache_i, pos=pos, decode=decode,
-                                 use_tri=use_tri)
+        x, nc, aux, *r = block_apply(kind, cfg, params_tuple[i], x,
+                                     cache=cache_i, pos=pos, decode=decode,
+                                     use_tri=use_tri, expert_rows=expert_rows)
         if constrain is not None:
             x = constrain(x, "activation")
         new_caches.append(nc)
         aux_total = aux_total + aux
+        rows += r
+    if expert_rows:
+        return x, tuple(new_caches), aux_total, tuple(rows)
     return x, tuple(new_caches), aux_total
 
 
@@ -198,25 +209,29 @@ def _sqrt_divisor(n: int) -> int:
 
 def segment_scan(pattern, repeats, cfg, seg_params, x, *, seg_caches=None,
                  pos=None, decode=False, use_tri=False, remat=False,
-                 collect_cache=False, constrain=None):
-    """Run ``repeats`` stacked super-blocks.  Returns (x, caches, aux).
+                 collect_cache=False, constrain=None, expert_rows=False):
+    """Run ``repeats`` stacked super-blocks.  Returns (x, caches, aux), and
+    with ``expert_rows`` (no caches) also a tuple over the pattern of each
+    block's (repeats, held) routed rows, None for a block without experts.
 
     Training (remat=True, no caches) uses TWO-LEVEL sqrt(R) checkpointing:
     an outer scan over groups saves only R/k boundaries; the rematted inner
     scan over k blocks recomputes within each group — peak saved activations
     drop from R to ~2*sqrt(R) layer boundaries.
     """
+    assert not (expert_rows and (collect_cache or seg_caches is not None))
+
     def body(carry, xs):
         x, aux = carry
         if seg_caches is not None:
             p, c = xs
         else:
             p, c = xs, None
-        x, nc, a = superblock_apply(pattern, cfg, p, x, caches_tuple=c,
-                                    pos=pos, decode=decode, use_tri=use_tri,
-                                    constrain=constrain)
+        x, nc, a, *rows = superblock_apply(
+            pattern, cfg, p, x, caches_tuple=c, pos=pos, decode=decode,
+            use_tri=use_tri, constrain=constrain, expert_rows=expert_rows)
         out = nc if (collect_cache or seg_caches is not None) else None
-        return (x, aux + a), out
+        return (x, aux + a), (rows[0] if expert_rows else out)
 
     if remat and seg_caches is None and not collect_cache and repeats >= 4:
         k = _sqrt_divisor(repeats)
@@ -227,16 +242,19 @@ def segment_scan(pattern, repeats, cfg, seg_params, x, *, seg_caches=None,
 
             @jax.checkpoint
             def outer_body(carry, p_grp):
-                (x2, aux2), _ = jax.lax.scan(jax.checkpoint(body),
-                                             carry, p_grp)
-                return (x2, aux2), None
+                return jax.lax.scan(jax.checkpoint(body), carry, p_grp)
 
-            (x, aux), _ = jax.lax.scan(
+            (x, aux), rows = jax.lax.scan(
                 outer_body, (x, jnp.zeros((), jnp.float32)), grouped)
+            if expert_rows:
+                return x, None, aux, jax.tree.map(
+                    lambda l: l.reshape(repeats, *l.shape[2:]), rows)
             return x, None, aux
 
     if remat:
         body = jax.checkpoint(body)
     xs = (seg_params, seg_caches) if seg_caches is not None else seg_params
-    (x, aux), caches = jax.lax.scan(body, (x, jnp.zeros((), jnp.float32)), xs)
-    return x, caches, aux
+    (x, aux), ys = jax.lax.scan(body, (x, jnp.zeros((), jnp.float32)), xs)
+    if expert_rows:
+        return x, None, aux, ys
+    return x, ys, aux

@@ -35,6 +35,7 @@ from repro.models import make_model
 from repro.serving.batching import EndpointBatcher, pad_batch
 from repro.serving.executor import Executor
 from repro.serving.weights import WeightStore
+from repro.telemetry import current_span
 
 
 @dataclass
@@ -74,10 +75,20 @@ class ModelEndpoint:
         self.prefetch_ttl = prefetch_ttl
         self.warm_budget = warm_budget or WarmBudget(min_repetitions=0)
         self.timings: List[dict] = []
+        # an endpoint with expert layers also returns, from its score
+        # step, the rows each held expert computed, per expert layer
+        self.counts_expert_rows = any(
+            k in ("attn_moe", "mla_moe") for k in cfg.layer_kinds)
 
     # ------------------------------------------------------------------
     def _score_fn(self):
         model = self.model
+
+        if self.counts_expert_rows:
+            def score(params, tokens):
+                x, _, rows = model.forward(params, tokens, expert_rows=True)
+                return model._logits(params, x[:, -1:]), rows
+            return score
 
         def score(params, tokens):
             x, _ = model.forward(params, tokens)
@@ -206,9 +217,13 @@ class ModelEndpoint:
         t_u = time.monotonic()
         extra = ctx.fr_fetch(3) if len(ctx.runtime.fr_state.plan) > 3 else None
         logits = compiled(params, tokens)
+        if self.counts_expert_rows:
+            logits, rows = logits
         logits = jax.block_until_ready(logits)
         # fabriclint: allow[clock] -- measured run-phase timing is a wall-clock contract
         t1 = time.monotonic()
+        if self.counts_expert_rows and (span := current_span()):
+            span.annotate(expert_rows=np.asarray(rows).tolist())
         self.warm_budget.observe((self.name, self.batch_size, self.seq_len))
         timing = {"total": t1 - t0, "weights": t_w - t0,
                   "compile": t_c - t_w, "warmup": t_u - t_c,
