@@ -10,13 +10,14 @@ Two batchers live here:
   tests); its per-batch fill counts now land in a bounded registry
   ``Histogram`` instead of an unbounded list.
 * ``EndpointBatcher`` — the pool-aware batcher ``ServingEngine.deploy``
-  installs in front of a deployed endpoint.  It drains its queue into
-  batches sized ``min(configured, queue_depth, idle_capacity())`` so the
-  batch it forms matches what the fabric can actually run *right now*,
-  dispatches each batch as ONE pooled invocation (one acquire/release,
-  one span), and treats ``PoolSaturated`` as backpressure: the batch is
-  requeued at the front and retried, never surfaced to callers as an
-  error.
+  installs in front of a deployed endpoint.  It dispatches at once when
+  the fabric has a free slot (the queued rows spread over the free
+  slots), dispatches a full batch at once, and otherwise holds rows while
+  one of its own batches is in flight, so they leave together when that
+  batch frees its instance.  Each batch runs as ONE pooled invocation
+  (one acquire/release, one span), and ``PoolSaturated`` is
+  backpressure: the batch is requeued at the front and retried, never
+  surfaced to callers as an error.
 """
 from __future__ import annotations
 
@@ -26,7 +27,7 @@ import time
 from collections import deque
 from concurrent.futures import Future
 from dataclasses import dataclass, field
-from typing import Any, Callable, Deque, List, Optional
+from typing import Any, Callable, Deque, List, Optional, Tuple
 
 import numpy as np
 
@@ -208,23 +209,37 @@ class EndpointBatcher:
     submitted)``, ``submitted`` the rows' ``Request.submitted_at``, for
     the span's ``batch_wait`` phase.
 
-    The batcher is *fabric-aware* through two read-only signals:
+    The batcher decides when a batch leaves from what it can observe: its
+    queue depth, its own batches in flight, and ``capacity()``, the
+    fabric's free slots for the endpoint (idle instances plus
+    unprovisioned ones: ``InstancePool.idle_capacity``, or the
+    cluster-wide sum).  A batch leaves under the first rule that holds,
+    counted as ``dispatch.<rule>``:
 
-    * ``capacity()`` — how many more invocations the endpoint's pool(s)
-      could start without queueing (``InstancePool.idle_capacity``, or the
-      cluster-wide sum).  The adaptive fill is
-      ``min(batch_size, queue_depth, max(1, capacity))``: when the fabric
-      has room, several smaller batches dispatch concurrently across warm
-      instances instead of one large batch serializing behind a single
-      acquire; when it is tight, batches grow toward the configured size
-      so each acquire amortizes more requests.
-    * ``PoolSaturated`` resolving a dispatched batch — backpressure, not
-      an error: the batch re-enters the queue at the *front* (admission
-      order holds) and is retried after ``retry_interval``.
+    * ``free`` — a slot is free: dispatch now, the queued rows spread over
+      the free slots, ``min(batch_size, ceil(queued / capacity))`` each.
+      One idle instance takes every queued row up to ``batch_size``.
+    * ``full`` — ``batch_size`` rows are queued: dispatch now, even with
+      no free slot; the batch waits in the pool's queue and gets the
+      instance straight from ``release``.
+    * otherwise the rows are held while a batch of this batcher is in
+      flight.  Its completion wakes the batcher after the pool has
+      released the instance, so the held rows leave together as ``free``.
+    * ``deadline`` — no slot free and nothing of this batcher in flight
+      (a prewarm or a direct ``submit`` holds the instance): the rows
+      leave ``max_wait`` after the oldest of them.  ``close()`` ends a
+      wait the same way.
 
-    Requests never error out because the platform was momentarily full;
-    only ``close()`` or a genuine handler failure resolves their futures
-    exceptionally."""
+    Without a ``capacity`` signal (or when it raises) batches fill toward
+    ``batch_size`` and leave ``max_wait`` after their oldest row.
+
+    ``PoolSaturated`` resolving a dispatched batch is backpressure, not
+    an error: the batch re-enters the queue at the *front* (admission
+    order holds) and is retried after ``retry_interval``.  Requests never
+    error out because the platform was momentarily full; only ``close()``
+    or a genuine handler failure resolves their futures exceptionally."""
+
+    DISPATCH_RULES = ("free", "full", "deadline")
 
     def __init__(self, name: str,
                  run_batch: Callable[[List[Any]], Future],
@@ -251,6 +266,8 @@ class EndpointBatcher:
         self._c_batches = self.metrics.counter("batches")
         self._c_requests = self.metrics.counter("requests")
         self._c_backpressure = self.metrics.counter("backpressure")
+        self._c_dispatch = {rule: self.metrics.counter(f"dispatch.{rule}")
+                            for rule in self.DISPATCH_RULES}
         self._h_fill = self.metrics.histogram("batch.fill")
         self.batch_fill: Deque[int] = deque(maxlen=FILL_VIEW_LIMIT)
         self._th = threading.Thread(target=self._loop, daemon=True,
@@ -272,48 +289,55 @@ class EndpointBatcher:
             return len(self._pending)
 
     # -- batch formation ------------------------------------------------
-    def _target_fill_locked(self) -> int:
-        """Adaptive fill under the lock: never more than what is queued,
-        never more than the configured executable batch, and — when the
-        fabric signal is wired — no larger than what the pool could run
-        now (floor 1: a saturated fabric still forms a batch; saturation
-        is handled as backpressure at dispatch, not starvation here)."""
-        target = min(self.batch_size, len(self._pending))
-        if self.capacity is not None:
-            try:
-                target = min(target, max(1, self.capacity()))
-            except Exception:
-                pass                     # a torn signal never stalls a batch
-        return max(1, target)
+    def _free_slots_locked(self) -> Optional[int]:
+        """The fabric's free slots, or None without a signal (a torn
+        signal never stalls a batch: it reads as no signal)."""
+        if self.capacity is None:
+            return None
+        try:
+            return max(0, int(self.capacity()))
+        except Exception:
+            return None
+
+    def _next_batch_locked(self) -> Tuple[Optional[str], int,
+                                          Optional[float]]:
+        """``(rule, fill, None)`` for a batch that leaves now, else
+        ``(None, 0, timeout)``: how long to wait before looking again,
+        None for until woken (a submit, a batch's end, ``close``)."""
+        queued = len(self._pending)
+        if self._stop:
+            return "deadline", min(self.batch_size, queued), None
+        free = self._free_slots_locked()
+        if free:
+            return "free", min(self.batch_size, -(-queued // free)), None
+        if queued >= self.batch_size:
+            return "full", self.batch_size, None
+        if free is not None and self._inflight:
+            return None, 0, None
+        remaining = (self._pending[0].submitted_at + self.max_wait
+                     - self.clock())
+        if remaining <= 0:
+            return "deadline", queued, None
+        return None, 0, remaining
 
     def _loop(self):
         while True:
             with self._cond:
-                while not self._pending and not self._stop:
-                    self._cond.wait(0.1)
-                if self._stop and not self._pending:
-                    return
-                first_at = self._pending[0].submitted_at
-            # deadline anchored at the OLDEST pending request: a trickle
-            # never postpones the flush
-            deadline = first_at + self.max_wait
-            with self._cond:
-                while (len(self._pending) < self.batch_size
-                       and not self._stop):
-                    remaining = deadline - self.clock()
-                    if remaining <= 0:
-                        break
-                    self._cond.wait(remaining)
+                while True:
                     if not self._pending:
+                        if self._stop:
+                            return
+                        self._cond.wait(0.1)
+                        continue
+                    rule, fill, timeout = self._next_batch_locked()
+                    if rule is not None:
                         break
-                if not self._pending:
-                    continue
-                fill = self._target_fill_locked()
+                    self._cond.wait(timeout)
                 batch = [self._pending.popleft() for _ in range(fill)]
-            self._dispatch(batch)
+            self._dispatch(batch, rule)
 
     # -- dispatch + backpressure ----------------------------------------
-    def _dispatch(self, batch: List[Request]):
+    def _dispatch(self, batch: List[Request], rule: str):
         payloads = [r.payload for r in batch]
         try:
             fut = (self.run_batch(payloads, [r.submitted_at for r in batch])
@@ -326,17 +350,22 @@ class EndpointBatcher:
             return
         with self._cond:
             self._inflight += 1
-        fut.add_done_callback(lambda f: self._batch_done(batch, f))
+        fut.add_done_callback(lambda f: self._batch_done(batch, rule, f))
 
-    def _batch_done(self, batch: List[Request], fut: Future):
-        with self._cond:
-            self._inflight -= 1
+    def _batch_done(self, batch: List[Request], rule: str, fut: Future):
         try:
             exc = fut.exception()
         except BaseException as e:       # cancelled
             exc = e
-        if isinstance(exc, PoolSaturated):
+        saturated = isinstance(exc, PoolSaturated)
+        if saturated:                    # requeued while still in flight
             self._backpressure(batch)
+        with self._cond:
+            self._inflight -= 1
+            # held rows look again: the pool released the instance before
+            # this future resolved
+            self._cond.notify()
+        if saturated:
             return
         if exc is not None:
             self._fail(batch, exc)
@@ -354,6 +383,7 @@ class EndpointBatcher:
             self._fail(batch, e)
             return
         self._c_batches.inc()
+        self._c_dispatch[rule].inc()
         self._c_requests.inc(len(batch))
         self._h_fill.observe(len(batch))
         self.batch_fill.append(len(batch))
@@ -413,6 +443,8 @@ class EndpointBatcher:
         return {"batches": self._c_batches.value,
                 "requests": self._c_requests.value,
                 "backpressure": self._c_backpressure.value,
+                "dispatch": {rule: c.value
+                             for rule, c in self._c_dispatch.items()},
                 "mean_fill": summary["mean"] if summary["count"] else 0.0,
                 "queue_depth": depth, "inflight": inflight}
 

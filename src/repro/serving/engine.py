@@ -414,9 +414,13 @@ class ServingEngine:
 
         ``batch_size=N`` installs a pool-aware ``EndpointBatcher`` in
         front of the endpoint: single token rows admitted through
-        ``submit_request`` are formed into adaptively-sized batches
-        (never larger than N, the queue depth, or the fabric's current
-        idle capacity) and each batch runs as ONE pooled invocation —
+        ``submit_request`` leave at once when the endpoint has an idle
+        instance (every queued row, up to N, spread over the free
+        slots) or when N rows are queued; while the endpoint's instance
+        runs one of its batches, rows gather behind it and leave
+        together when it is released.  ``batch_max_wait`` bounds only a
+        wait nothing else ends (the instance held by a prewarm or a
+        direct ``submit``).  Each batch runs as ONE pooled invocation —
         one acquire/release, one span annotated with the fill count.
         Saturation backpressures the batcher instead of failing
         requests.  N is clamped to the endpoint's compiled batch shape
@@ -455,9 +459,9 @@ class ServingEngine:
 
     # -- pool-aware batching --------------------------------------------
     def _idle_capacity(self, name: str) -> int:
-        """The fabric signal the endpoint batcher sizes against: idle
-        instances plus cap headroom, summed across shards when the
-        endpoint lives on the cluster."""
+        """The fabric signal the endpoint batcher dispatches on: free
+        slots (idle instances plus cap headroom), summed across shards
+        when the endpoint lives on the cluster."""
         if self.cluster is not None and name in self._clustered:
             return sum(w.idle_capacity(name) for w in self.cluster.workers)
         pool = self.scheduler.pools.get(name)

@@ -348,22 +348,46 @@ def test_endpoint_batcher_fills_and_resolves_in_order():
     b.close()
 
 
+def _dispatch_adds_up(b):
+    s = b.stats()
+    assert sum(s["dispatch"].values()) == s["batches"]
+    snap = b.metrics_snapshot()
+    assert sum(snap[f"batcher.{b.name}.dispatch.{rule}"]
+               for rule in EndpointBatcher.DISPATCH_RULES) == s["batches"]
+    return s["dispatch"]
+
+
 def test_endpoint_batcher_adapts_fill_to_fabric_capacity():
-    """With idle capacity below the configured batch size, batches shrink
-    to what the pool can actually run concurrently."""
-    fills = []
+    """Queued rows spread over the free slots: 12 rows over 2 slots leave
+    in batches of at most ceil(12 / 2); over 1 slot, of at most
+    ``batch_size``."""
+    for slots, expected in ((2, [6, 3, 2, 1]), (1, [8, 4])):
+        fills = []
+        entered, gate = threading.Event(), threading.Event()
 
-    def handler(payloads):
-        fills.append(len(payloads))
-        time.sleep(0.005)
-        return list(payloads)
+        def handler(payloads, fills=fills, entered=entered, gate=gate):
+            if payloads == ["hold"]:     # holds the worker in run_batch
+                entered.set()
+                gate.wait(5)
+            else:
+                fills.append(len(payloads))
+            return list(payloads)
 
-    b = EndpointBatcher("t", _sync_batches(handler), batch_size=8,
-                        max_wait=0.01, capacity=lambda: 2)
-    futs = [b.submit(i) for i in range(12)]
-    assert [f.result(5) for f in futs] == list(range(12))
-    assert max(fills) <= 2, fills
-    b.close()
+        b = EndpointBatcher("t", _sync_batches(handler), batch_size=8,
+                            max_wait=0.01, capacity=lambda s=slots: s)
+        try:
+            hold = b.submit("hold")
+            assert entered.wait(5)       # the worker is inside run_batch
+            futs = [b.submit(i) for i in range(12)]
+            gate.set()
+            assert hold.result(5) == "hold"
+            assert [f.result(5) for f in futs] == list(range(12))
+            assert fills == expected
+            assert max(fills) <= min(8, -(-12 // slots))
+            assert _dispatch_adds_up(b) == {"free": 1 + len(expected),
+                                            "full": 0, "deadline": 0}
+        finally:
+            b.close()
 
 
 def test_endpoint_batcher_backpressures_on_saturation():
@@ -387,9 +411,15 @@ def test_endpoint_batcher_backpressures_on_saturation():
 
 
 def test_endpoint_batcher_runs_batches_as_single_pooled_invocations():
-    """End to end against a real scheduler: one batch = one acquire."""
-    spec = FunctionSpec("m", lambda ctx, args: [p + 1 for p in args],
-                        app="hot")
+    """End to end against a real scheduler: one batch = one acquire.  The
+    instances stay busy until every row is queued, so rows gather."""
+    gate = threading.Event()
+
+    def fn(ctx, args):
+        gate.wait(5)
+        return [p + 1 for p in args]
+
+    spec = FunctionSpec("m", fn, app="hot")
     sched = FreshenScheduler(pool_config=PoolConfig(max_instances=2))
     sched.register(spec)
     pool = sched.pools["m"]
@@ -400,13 +430,109 @@ def test_endpoint_batcher_runs_batches_as_single_pooled_invocations():
         batch_size=4, max_wait=0.02, capacity=pool.idle_capacity)
     try:
         futs = [b.submit(i) for i in range(8)]
+        gate.set()
         assert [f.result(5) for f in futs] == [i + 1 for i in range(8)]
         s = pool.stats()
         invocations = s["cold_starts"] + s["warm_acquires"]
-        assert invocations == b.stats()["batches"] < 8
+        # two rows take the two slots, the rest gather: at most one full
+        # batch and one batch of the rows held behind it
+        assert invocations == b.stats()["batches"] <= 4
+        assert sum(b.batch_fill) == 8
+        _dispatch_adds_up(b)
     finally:
         b.close()
         sched.shutdown()
+
+
+@pytest.mark.parametrize("queued,expected", [(3, [1, 3]), (6, [1, 4, 2])])
+def test_endpoint_batcher_holds_rows_behind_a_busy_instance(queued,
+                                                            expected):
+    """One instance (``max_instances`` 1) busy with the batcher's first
+    row: rows queue behind it, a full batch waits in the pool's queue,
+    and the rest leave as one batch of ``min(B, queued)`` once it is
+    released, without waiting out ``max_wait``."""
+    entered, gate = threading.Event(), threading.Event()
+
+    def fn(ctx, args):
+        entered.set()
+        gate.wait(5)
+        return [p + 1 for p in args]
+
+    sched = FreshenScheduler(pool_config=PoolConfig(max_instances=1))
+    sched.register(FunctionSpec("m", fn, app="hot"))
+    pool = sched.pools["m"]
+    b = EndpointBatcher(
+        "m", lambda payloads: sched.submit("m", list(payloads),
+                                           freshen_successors=False),
+        batch_size=4, max_wait=5.0, capacity=pool.idle_capacity)
+    try:
+        first = b.submit(0)
+        assert entered.wait(5)
+        futs = [b.submit(i) for i in range(1, queued + 1)]
+        deadline = time.monotonic() + 5
+        while (b.queue_depth(), pool.async_waiting_count()) != (
+                queued % 4, queued // 4):
+            assert time.monotonic() < deadline, (b.queue_depth(),
+                                                 pool.async_waiting_count())
+            time.sleep(0.001)
+        assert b.stats()["batches"] == 0     # nothing has left since
+        t0 = time.monotonic()
+        gate.set()
+        assert first.result(5) == 1
+        assert [f.result(5) for f in futs] == [i + 1 for i in
+                                               range(1, queued + 1)]
+        assert time.monotonic() - t0 < 2.0   # not the 5-s deadline
+        assert sorted(b.batch_fill) == sorted(expected)
+        assert _dispatch_adds_up(b) == {"free": 2, "full": queued // 4,
+                                        "deadline": 0}
+    finally:
+        b.close()
+        sched.shutdown()
+
+
+def test_endpoint_batcher_free_slot_dispatches_at_once():
+    """A free slot takes a lone row at once: ``max_wait`` is not waited."""
+    b = EndpointBatcher("t", _sync_batches(list), batch_size=4,
+                        max_wait=5.0, capacity=lambda: 1)
+    try:
+        t0 = time.monotonic()
+        assert b.submit(7).result(5) == 7
+        assert time.monotonic() - t0 < 1.0
+        assert _dispatch_adds_up(b) == {"free": 1, "full": 0,
+                                        "deadline": 0}
+    finally:
+        b.close()
+
+
+def test_endpoint_batcher_batch_of_one_dispatches_every_row_at_once():
+    """At ``batch_size`` 1 every row is a full batch, slot or none."""
+    b = EndpointBatcher("t", _sync_batches(list), batch_size=1,
+                        max_wait=5.0, capacity=lambda: 0)
+    try:
+        t0 = time.monotonic()
+        assert [b.submit(i).result(5) for i in range(3)] == [0, 1, 2]
+        assert time.monotonic() - t0 < 1.0
+        assert list(b.batch_fill) == [1, 1, 1]
+        assert _dispatch_adds_up(b) == {"free": 0, "full": 3,
+                                        "deadline": 0}
+    finally:
+        b.close()
+
+
+def test_endpoint_batcher_deadline_guard_without_free_slot():
+    """No slot free and nothing of the batcher in flight (a prewarm or a
+    direct submit holds the instance): the row still leaves after
+    ``max_wait`` from its submit."""
+    b = EndpointBatcher("t", _sync_batches(list), batch_size=4,
+                        max_wait=0.05, capacity=lambda: 0)
+    try:
+        t0 = time.monotonic()
+        assert b.submit(3).result(5) == 3
+        assert time.monotonic() - t0 >= 0.05
+        assert _dispatch_adds_up(b) == {"free": 0, "full": 0,
+                                        "deadline": 1}
+    finally:
+        b.close()
 
 
 def test_endpoint_batcher_close_drains_pending():
