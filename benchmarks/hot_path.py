@@ -1,40 +1,34 @@
 """Single-submission hot path: sustained RPS per shard and client p50
-for the three admission modes.
+of the scheduler's admission, alone and behind the endpoint batcher.
 
-Three arms, same closed-loop workload (each client thread fires its next
+Two arms, same closed-loop workload (each client thread fires its next
 request the moment the previous one resolves — the steady-state regime
 where admission overhead, not burst queueing, bounds throughput):
 
-* **legacy** — ``fast_path=False``: the PR 8 two-hop admission (submit
-  enqueues the whole invocation; a router thread then observes the
-  predictor, blocks in ``acquire``, runs, releases).
-* **fast** — ``fast_path=True``: the caller thread ``try_acquire``s
-  inline and dispatches a run-only tail; prediction freshening moves to
+* **fast** — a two-shard cluster: the caller thread ``try_acquire``s
+  inline and dispatches a run-only tail; prediction freshening runs on
   a dedicated low-priority executor.  A warm hit pays no admission hop
   for the acquire and no predictor work on the critical path.
-* **batched** — the fast path behind a pool-aware ``EndpointBatcher``:
-  single requests coalesce into adaptively-sized batches, each batch one
-  pooled invocation — per-request platform overhead divides by the fill.
+* **batched** — the same admission behind a pool-aware
+  ``EndpointBatcher``: single requests coalesce into adaptively-sized
+  batches, each batch one pooled invocation — per-request platform
+  overhead divides by the fill.
 
 Reported per arm: client-observed p50/p95, completed requests, wall
-time, RPS per shard.  The two cluster arms also run under a fabric
-``Tracer`` so the phase breakdown shows the warm-hit ``queue`` share
-collapsing on the fast path, and the fast arm's
-``invoke.fast_path`` / ``invoke.slow_path`` counters are read back from
-the metrics registry.
+time, RPS per shard.  The cluster arm also runs under a fabric
+``Tracer`` for the phase breakdown, and its ``invoke.fast_path`` /
+``invoke.slow_path`` counters are read back from the metrics registry.
 
-The verdict row carries the CI gates (grep-able key=value):
-``fast_p50_le_legacy=1`` (fast-path p50 no worse than legacy) and
+The verdict row carries the CI gate (grep-able key=value):
 ``fast_path_gt0=1`` (the fast-path counter actually moved — the inline
-admission is exercised, not silently bypassed), plus the RPS ratios
-backing the ROADMAP's >=2x-per-shard target (``batched_ratio`` is the
-arm that clears it; ``fast_ratio`` prices the hop removal alone).
+admission is exercised, not silently bypassed), plus ``batched_ratio``,
+the batched arm's RPS per shard over the fast arm's.
 
-``HOT_PATH_SMOKE=1`` shrinks the run for CI (same arms and gates, fewer
+``HOT_PATH_SMOKE=1`` shrinks the run for CI (same arms and gate, fewer
 requests).
 
 CSV rows (stdout; schema in docs/benchmarks.md): ``name`` is
-``hot_path/<legacy|fast|batched|phase/<arm>/<phase>|counters|verdict>``.
+``hot_path/<fast|batched|phase/fast/<phase>|counters|batch_fill|verdict>``.
 
 Run on CPU:  PYTHONPATH=src python benchmarks/hot_path.py
 (or: PYTHONPATH=src:. python benchmarks/run.py hot_path)
@@ -102,12 +96,12 @@ def _closed_loop(submit, n_clients: int, per_client: int):
     return [x for per in lats for x in per], wall
 
 
-def _drive_cluster(fast_path: bool):
-    """One cluster arm; returns (lats, wall, phase_totals, counters)."""
+def _drive_cluster():
+    """The cluster arm; returns (lats, wall, phase_totals, counters)."""
     tracer = Tracer(capacity=16384)
     cluster = ClusterRouter.build(
         SHARDS, policy="least-loaded", pool_config=PoolConfig(**POOL),
-        max_router_threads=16, tracer=tracer, fast_path=fast_path)
+        max_router_threads=16, tracer=tracer)
     cluster.register(_spec())
     for w in cluster.workers:
         w.scheduler.accountant.service_class["bench"] = \
@@ -128,9 +122,9 @@ def _drive_cluster(fast_path: bool):
 
 
 def _drive_batched():
-    """Fast path + EndpointBatcher on one scheduler (one shard)."""
+    """Admission + EndpointBatcher on one scheduler (one shard)."""
     sched = FreshenScheduler(pool_config=PoolConfig(**POOL),
-                             max_router_threads=16, fast_path=True)
+                             max_router_threads=16)
     sched.register(_spec(batched=True))
     pool = sched.pools["hot"]
 
@@ -163,37 +157,31 @@ def run():
     """Harness entry (benchmarks/run.py): CSV rows name,us_per_call,derived."""
     err = sys.stderr
     n = CLIENTS * REQS_PER_CLIENT
-    legacy_lats, legacy_wall, legacy_phases, _ = _drive_cluster(False)
-    fast_lats, fast_wall, fast_phases, counters = _drive_cluster(True)
+    fast_lats, fast_wall, fast_phases, counters = _drive_cluster()
     bat_lats, bat_wall, bat_stats = _drive_batched()
 
-    legacy_p50, _, legacy_rps, legacy_row = _row("legacy", legacy_lats,
-                                                 legacy_wall, SHARDS)
     fast_p50, _, fast_rps, fast_row = _row("fast", fast_lats, fast_wall,
                                            SHARDS)
     bat_p50, _, bat_rps, bat_row = _row("batched", bat_lats, bat_wall, 1)
-    rows = [legacy_row, fast_row, bat_row]
+    rows = [fast_row, bat_row]
 
     print(f"\n=== hot_path ({n} requests, {CLIENTS} clients, {SHARDS} "
           f"shards{', SMOKE' if SMOKE else ''}) ===", file=err)
-    for arm, p50, rps in (("legacy", legacy_p50, legacy_rps),
-                          ("fast", fast_p50, fast_rps),
+    for arm, p50, rps in (("fast", fast_p50, fast_rps),
                           ("batched", bat_p50, bat_rps)):
         print(f"{arm:>8s}: p50 {p50*1e6:7.0f}us  {rps:7.0f} rps/shard",
               file=err)
 
-    # phase shares: the warm-hit admission cost is route+queue; the fast
-    # path should shrink its share of total traced time
-    for arm, phases in (("legacy", legacy_phases), ("fast", fast_phases)):
-        total = sum(t["seconds"] for t in phases.values()) or 1.0
-        for name, t in sorted(phases.items()):
-            share = t["seconds"] / total
-            rows.append((f"hot_path/phase/{arm}/{name}",
-                         f"{t['mean']*1e6:.0f}",
-                         f"count={t['count']};share_pct={share*100:.1f}"))
-        adm = sum(phases.get(p, {"seconds": 0.0})["seconds"]
-                  for p in ("route", "queue")) / total
-        print(f"{arm:>8s}: route+queue share {adm:.1%}", file=err)
+    # phase shares: the warm-hit admission cost is route+queue
+    total = sum(t["seconds"] for t in fast_phases.values()) or 1.0
+    for name, t in sorted(fast_phases.items()):
+        share = t["seconds"] / total
+        rows.append((f"hot_path/phase/fast/{name}",
+                     f"{t['mean']*1e6:.0f}",
+                     f"count={t['count']};share_pct={share*100:.1f}"))
+    adm = sum(fast_phases.get(p, {"seconds": 0.0})["seconds"]
+              for p in ("route", "queue")) / total
+    print(f"    fast: route+queue share {adm:.1%}", file=err)
 
     rows.append(("hot_path/counters", "0",
                  f"fast_path={counters['fast']};"
@@ -203,20 +191,12 @@ def run():
                  f"batches={bat_stats['batches']};"
                  f"backpressure={bat_stats['backpressure']}"))
 
-    fast_ratio = fast_rps / legacy_rps if legacy_rps else 0.0
-    bat_ratio = bat_rps / legacy_rps if legacy_rps else 0.0
-    # p50 "flat": within 10% of legacy (it should in fact be lower — one
-    # executor hop and the predictor work leave the critical path)
-    p50_ok = int(fast_p50 <= legacy_p50 * 1.10)
+    bat_ratio = bat_rps / fast_rps if fast_rps else 0.0
     fp_ok = int(counters["fast"] > 0)
-    print(f"verdict: fast_p50_le_legacy={p50_ok} fast_path_gt0={fp_ok} "
-          f"fast_ratio={fast_ratio:.2f} batched_ratio={bat_ratio:.2f}",
+    print(f"verdict: fast_path_gt0={fp_ok} batched_ratio={bat_ratio:.2f}",
           file=err)
     rows.append(("hot_path/verdict", "0",
-                 f"fast_p50_le_legacy={p50_ok};fast_path_gt0={fp_ok};"
-                 f"fast_ratio={fast_ratio:.2f};"
-                 f"batched_ratio={bat_ratio:.2f};"
-                 f"speedup_ge2={int(max(fast_ratio, bat_ratio) >= 2.0)}"))
+                 f"fast_path_gt0={fp_ok};batched_ratio={bat_ratio:.2f}"))
     return rows
 
 

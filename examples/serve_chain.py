@@ -5,7 +5,8 @@ the JAX substrate.
 Stage chain:  embed-small -> rank-medium -> generate-small
 The platform knows the chain (orchestration DAG), so invoking stage k
 freshens stage k+1 (weights, XLA executable, warmup) inside the trigger
-window.  Requests are batched by the Batcher.
+window.  Single requests are admitted with ``submit_request`` and batched
+by each endpoint's ``EndpointBatcher`` (``deploy(..., batch_size=)``).
 
 Platform architecture (see repro.core.pool / repro.core.scheduler): each
 deployed endpoint is backed by an InstancePool of warm containers — idle
@@ -33,10 +34,10 @@ import jax
 import numpy as np
 
 from repro.configs import get_config
+from repro.core import ServiceClass
 from repro.launch.compile_cache import configure_compile_cache
 from repro.models import make_model
-from repro.serving import (Batcher, Executor, ModelEndpoint, ServingEngine,
-                           WeightStore, pad_batch)
+from repro.serving import Executor, ModelEndpoint, ServingEngine, WeightStore
 
 BATCH, SEQ = 4, 32
 
@@ -52,39 +53,29 @@ def build(freshen_on: bool):
         cfg = dataclasses.replace(cfg, vocab_size=512)
         store.publish(name, make_model(cfg).init(jax.random.PRNGKey(i)))
         eng.deploy(ModelEndpoint(name, cfg, store, Executor(),
-                                 batch_size=BATCH, seq_len=SEQ))
+                                 batch_size=BATCH, seq_len=SEQ),
+                   batch_size=BATCH, batch_max_wait=0.02)
     if freshen_on:
         eng.chain(stages)
+    else:
+        # the BATCH service class never freshens: predictions still run,
+        # the accountant gates every dispatch
+        eng.scheduler.accountant.service_class["serving"] = \
+            ServiceClass.BATCH
     return eng, stages
 
 
-def run_pipeline(eng, stages, requests, freshen_on):
-    lat = {s: [] for s in stages}
-
-    def handler_for(stage):
-        def handler(payloads):
-            toks = pad_batch(payloads, BATCH)
-            out = eng.invoke(stage, toks, freshen_successors=freshen_on)
-            lat[stage].append(out["timing"]["total"])
-            return [out["logits"][i] for i in range(len(payloads))]
-        return handler
-
-    batchers = {s: Batcher(BATCH, handler_for(s), max_wait=0.02)
-                for s in stages}
+def run_pipeline(eng, stages, requests):
     rng = np.random.default_rng(0)
     t0 = time.monotonic()
-    try:
-        for i in range(requests):
-            x = rng.integers(0, 512, size=(SEQ,), dtype=np.int32)
-            for s in stages:
-                fut = batchers[s].submit(x)
-                logits = fut.result(timeout=300)
-                x = np.argsort(logits[-1])[-SEQ:].astype(np.int32)  # feed fwd
-        wall = time.monotonic() - t0
-    finally:
-        # a failing request must not leak flush-timer threads
-        for b in batchers.values():
-            b.close()
+    for i in range(requests):
+        x = rng.integers(0, 512, size=(SEQ,), dtype=np.int32)
+        for s in stages:
+            logits = eng.submit_request(s, x).result(timeout=300)
+            x = np.argsort(logits[-1])[-SEQ:].astype(np.int32)  # feed fwd
+    wall = time.monotonic() - t0
+    # per-batch run-hook time, as each endpoint measured it
+    lat = {s: [t["total"] for t in eng.endpoints[s].timings] for s in stages}
     return lat, wall
 
 
@@ -97,7 +88,7 @@ if __name__ == "__main__":
     for mode in (False, True):
         eng, stages = build(freshen_on=mode)
         try:
-            lat, wall = run_pipeline(eng, stages, args.requests, mode)
+            lat, wall = run_pipeline(eng, stages, args.requests)
             label = "freshen ON " if mode else "freshen OFF"
             print(f"=== {label}: {args.requests} requests, "
                   f"wall {wall:.2f}s ===")
@@ -121,5 +112,5 @@ if __name__ == "__main__":
                       f"cold={ps['cold_starts']} hits={ps['hits']} "
                       f"inline={ps['inline']}")
         finally:
-            # router/worker threads must die even when the demo fails
+            # batcher/router threads must die even when the demo fails
             eng.close()

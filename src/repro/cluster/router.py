@@ -283,15 +283,12 @@ class ClusterRouter:
               max_router_threads: int = 16,
               spill_timeout: Optional[float] = None,
               cross_freshen: bool = True,
-              tracer: Optional[Tracer] = None,
-              fast_path: bool = True) -> "ClusterRouter":
+              tracer: Optional[Tracer] = None) -> "ClusterRouter":
         """A local cluster: ``num_shards`` workers sharing one predictor
         (prediction is global knowledge) and one tracer (spans must link
         across shards) with per-shard accountants.  ``devices`` (optional
         jax device list) is partitioned round-robin so each worker pins
-        its functions to a distinct slice.  ``fast_path=False`` restores
-        the two-hop admission on every shard (the hot-path benchmark's
-        legacy arm)."""
+        its functions to a distinct slice."""
         predictor = predictor or HybridPredictor()
         slices = partition_devices(devices, num_shards)
         workers = [ClusterWorker(k, predictor=predictor,
@@ -299,8 +296,7 @@ class ClusterRouter:
                                  pool_config=pool_config,
                                  devices=slices[k],
                                  max_router_threads=max_router_threads,
-                                 tracer=tracer,
-                                 fast_path=fast_path)
+                                 tracer=tracer)
                    for k in range(num_shards)]
         return cls(workers, policy=policy, spill_timeout=spill_timeout,
                    cross_freshen=cross_freshen, tracer=tracer)
@@ -395,8 +391,7 @@ class ClusterRouter:
                     devices=devices,
                     max_router_threads=(max_router_threads
                                         or template.max_router_threads),
-                    tracer=self.tracer if self.tracer.enabled else None,
-                    fast_path=template.fast_path)
+                    tracer=self.tracer if self.tracer.enabled else None)
             elif self.tracer.enabled and not worker.scheduler.tracer.enabled:
                 # adopted workers join the fabric-wide tracer too
                 worker.scheduler.tracer = self.tracer

@@ -43,8 +43,7 @@ class ClusterWorker:
                  pool_config: Optional[PoolConfig] = None,
                  devices: Optional[Sequence] = None,
                  max_router_threads: int = 16,
-                 tracer: Optional[Tracer] = None,
-                 fast_path: bool = True):
+                 tracer: Optional[Tracer] = None):
         self.shard_id = shard_id
         self.devices = list(devices) if devices else None
         # set by ClusterRouter.remove_worker: a draining shard finishes
@@ -52,14 +51,13 @@ class ClusterWorker:
         self.draining = False
         # like the predictor, the tracer is cluster-shared: a freshen
         # dispatched on this shard and the arrival it anchored (possibly
-        # routed elsewhere) must meet in one pending table.  fast_path
-        # threads the single-submission admission toggle through to the
-        # shard scheduler: a routed warm hit try_acquires inline on the
-        # router's calling thread and pays no admission hop.
+        # routed elsewhere) must meet in one pending table.  A routed
+        # warm hit try_acquires inline on the router's calling thread and
+        # pays no admission hop.
         self.scheduler = FreshenScheduler(
             predictor=predictor, accountant=accountant,
             pool_config=pool_config, max_router_threads=max_router_threads,
-            tracer=tracer, fast_path=fast_path)
+            tracer=tracer)
 
     # -- registration ---------------------------------------------------
     def _placement(self):

@@ -25,20 +25,20 @@ instance an invocation lands on is the one most likely to have been
 freshened — that is what makes per-instance ``fr_state`` prewarming pay
 off under load.
 
-Thread-safety: all pool state is guarded by one condition variable;
-``acquire`` blocks (measuring queueing delay) when the pool is saturated.
+Thread-safety: all pool state is guarded by one lock (``_cond``).
 
-Two admission modes share that state (event-driven lifecycle control,
-arxiv 2604.05465):
-
-* **Thread-parked** — the legacy blocking ``acquire(timeout)``: the
-  calling thread waits on the condition variable.
-* **Closure-parked** — ``try_acquire()`` grabs an instance without ever
-  blocking, and ``acquire_async(cb, timeout)`` parks a *callback* in an
-  admission-ordered waiter queue when nothing is available.  ``release``
-  hands the freed instance straight to the next parked waiter under the
-  same single lock acquisition (no executor round-trip); waiter timeouts
-  are swept by the ``AdaptDaemon`` tick via ``sweep_waiters``.
+Admission never blocks under that lock and has one waiter queue
+(event-driven lifecycle control, arxiv 2604.05465): ``try_acquire()``
+grabs an instance without ever blocking, and ``acquire_async(cb,
+timeout)`` parks a *callback* in an admission-ordered FIFO when nothing
+is available.  ``release`` hands the freed instance straight to the
+head waiter under the same lock hold (no executor round-trip), and
+every other capacity change (eviction, adoption, reconfigure, demotion,
+provisioning) serves the queue the same way.  Waiter deadlines are
+swept by the ``AdaptDaemon`` tick via ``sweep_waiters``.  The blocking
+``acquire(timeout)`` is a thin front over ``acquire_async``: its
+callback sets an event the calling thread waits on, so a blocked
+caller and a parked callback share one queue and one order.
 """
 from __future__ import annotations
 
@@ -136,10 +136,10 @@ AcquireCallback = Callable[
 
 @dataclass
 class _AsyncWaiter:
-    """One parked ``acquire_async`` request.  ``enqueued``/``deadline``
-    are ``time.monotonic``-domain (matching blocking ``acquire``'s
-    timeout semantics), NOT the injectable pool clock — waiter timeouts
-    are wall-clock contracts with the caller, not policy time."""
+    """One parked acquire.  ``enqueued``/``deadline`` are
+    ``time.monotonic``-domain, NOT the injectable pool clock — waiter
+    timeouts are wall-clock contracts with the caller, not policy
+    time."""
     cb: AcquireCallback
     enqueued: float
     deadline: Optional[float]
@@ -148,7 +148,7 @@ class _AsyncWaiter:
 
 
 class AcquireWaiter:
-    """Caller-side handle for one parked ``acquire_async`` request."""
+    """Caller-side handle for one parked acquire."""
     __slots__ = ("_pool", "_waiter")
 
     def __init__(self, pool: "InstancePool", waiter: _AsyncWaiter):
@@ -199,9 +199,9 @@ class InstancePool:
         self._instances: Dict[int, PooledInstance] = {}
         self._idle: List[PooledInstance] = []     # LIFO stack
         self._next_id = 0
-        self._waiting = 0
-        # admission-ordered FIFO of closure-parked acquires (acquire_async);
-        # cancelled waiters are removed eagerly, so len() is live demand
+        # the one admission-ordered FIFO of parked acquires (blocking or
+        # callback); cancelled waiters are removed eagerly, so len() is
+        # live demand
         self._async_waiters: Deque[_AsyncWaiter] = deque()
         self._retired = False         # retire(): released instances close
         # lifecycle counters live in the pool's own metrics registry;
@@ -327,7 +327,6 @@ class InstancePool:
             self._next_id += 1
             self._instances[inst.instance_id] = inst
             self._idle.append(inst)
-            self._cond.notify()
         self._pump_async()            # the adoptee may serve a parked waiter
         return inst
 
@@ -347,7 +346,6 @@ class InstancePool:
         with self._cond:
             if not self._instances:
                 self._create_locked()
-                self._cond.notify()
             rt = self._instances[min(self._instances)].runtime
         if not rt.initialized:
             # Idempotent and lock-guarded inside Runtime: concurrent callers
@@ -402,15 +400,9 @@ class InstancePool:
                        if not i.runtime.freshen_in_flight())
 
     def waiting_count(self) -> int:
-        """Acquires currently waiting for an instance (queue depth) —
-        thread-parked blocking acquires plus closure-parked async
-        waiters.  The load signal cluster routing and rebalancing read."""
-        with self._cond:
-            return self._waiting + len(self._async_waiters)
-
-    def async_waiting_count(self) -> int:
-        """Closure-parked waiters only (``acquire_async`` requests not
-        yet granted or timed out) — what a drain must wait out."""
+        """Acquires parked for an instance (queue depth): the load signal
+        cluster routing and rebalancing read, and what a drain must
+        wait out."""
         with self._cond:
             return len(self._async_waiters)
 
@@ -419,15 +411,14 @@ class InstancePool:
             return len(self._instances) - len(self._idle)
 
     def load(self) -> int:
-        """Busy instances + waiting acquires (both parking modes) under
-        ONE lock acquisition — the cluster load signal.  Summing
-        ``busy_count()`` and ``waiting_count()`` from outside tears: a
-        release between the two reads double-counts (the instance
-        already idle, the waiter not yet woken) and routing chases
-        phantom load."""
+        """Busy instances + parked acquires under ONE lock acquisition —
+        the cluster load signal.  Summing ``busy_count()`` and
+        ``waiting_count()`` from outside tears: a release between the two
+        reads double-counts (the instance already idle, the waiter not
+        yet served) and routing chases phantom load."""
         with self._cond:
-            return (len(self._instances) - len(self._idle)) \
-                + self._waiting + len(self._async_waiters)
+            return (len(self._instances) - len(self._idle)
+                    + len(self._async_waiters))
 
     def idle_capacity(self) -> int:
         """Immediately-usable headroom (idle instances + unprovisioned
@@ -531,7 +522,6 @@ class InstancePool:
                     # freshly demoted instance should be the last reused
                     self._idle.insert(0, inst)
                     self._c_demotions.inc()
-                    self._cond.notify()
         if failed:
             with self._cond:
                 for inst in failed:
@@ -539,11 +529,10 @@ class InstancePool:
                         inst.state = InstanceState.REAPED
                         del self._instances[inst.instance_id]
                         self._c_dead.inc()
-                        self._cond.notify()
             self._fold_and_close(failed, join_timeout=0.0)
         if demote:
-            # demoted instances re-entered the idle list: a parked
-            # waiter may land on one (paying only the missing rungs)
+            # demoted instances re-entered the idle list (or failed ones
+            # freed their slots): a parked waiter may land on one
             self._pump_async()
         return len(dead) + len(failed)
 
@@ -610,7 +599,7 @@ class InstancePool:
         call are closed instead of re-idled.  For pools on a shard that
         left its cluster undrained — a busy instance finishing later
         must not park a subprocess backend worker in an idle list nobody
-        will ever reap.  Closure-parked waiters are failed with
+        will ever reap.  Parked waiters are failed with
         ``PoolSaturated`` (their callbacks see the error — no admitted
         request silently drops)."""
         with self._cond:
@@ -646,23 +635,23 @@ class InstancePool:
         return self._idle.pop(best_i)
 
     def _scale_up_allowed_locked(self, extra_waiters: int = 0) -> bool:
-        """Demand counts thread-parked acquires (``_waiting`` includes a
-        blocked requester), closure-parked async waiters, and
-        ``extra_waiters`` for a requester not represented in either (a
-        ``try_acquire``/``acquire_async`` caller probing before parking)
-        — so with the default depth of 1 any arrival that finds no idle
-        instance provisions a new one."""
+        """Demand counts parked waiters plus ``extra_waiters`` for a
+        requester not yet in the queue (a ``try_acquire``/``acquire_async``
+        caller probing before parking) — so with the default depth of 1
+        any arrival that finds no idle instance provisions a new one."""
         if len(self._instances) >= self.config.max_instances:
             return False
         if not self._instances:
             return True                       # from zero: always start one
-        demand = self._waiting + len(self._async_waiters) + extra_waiters
+        demand = len(self._async_waiters) + extra_waiters
         return demand >= self.config.scale_up_queue_depth
 
-    def _saturated_locked(self) -> PoolSaturated:
+    def _saturated_locked(self, extra_waiters: int = 0) -> PoolSaturated:
+        """``extra_waiters`` counts a timed-out waiter already withdrawn
+        from the queue."""
         return PoolSaturated(
             self.spec.name,
-            queue_depth=self._waiting + len(self._async_waiters),
+            queue_depth=len(self._async_waiters) + extra_waiters,
             pool_size=len(self._instances),
             max_instances=self.config.max_instances,
             shard=self.shard)
@@ -712,46 +701,29 @@ class InstancePool:
 
     def acquire(self, timeout: Optional[float] = None
                 ) -> Tuple[PooledInstance, float, bool]:
-        """Claim an instance for one invocation (thread-parked mode).
+        """Claim an instance for one invocation, blocking the caller.
 
-        Returns ``(instance, queue_delay_seconds, cold_start)``.  Prefers
-        the most recently used idle instance (LIFO — the one a prewarm
-        freshen most likely touched); scales up when allowed; otherwise
-        blocks until a release, accumulating queueing delay.
+        Returns ``(instance, queue_delay_seconds, cold_start)``.  A thin
+        front over ``acquire_async``: the same warmth-aware LIFO pick,
+        scale-up and dead-instance eviction, and a place in the same
+        admission-ordered queue, with the calling thread waiting on an
+        event the grant sets.  Raises ``PoolSaturated`` when ``timeout``
+        seconds pass without a grant."""
+        done = threading.Event()
+        got: List[tuple] = []
 
-        An idle instance whose backend substrate died (subprocess worker
-        or snapshot fork killed) is evicted here instead of handed out:
-        dropping it shrinks the pool, so the same loop iteration may then
-        scale up a fresh instance rather than fail the invocation."""
-        # fabriclint: allow[clock] -- waiter deadlines/queue delay are wall-clock contracts
-        t0 = time.monotonic()
-        self.reap()
-        doomed: List[PooledInstance] = []
-        try:
+        def _granted(inst, queue_delay, cold, error):
+            got.append((inst, queue_delay, cold, error))
+            done.set()
+
+        handle = self.acquire_async(_granted)
+        if not done.wait(timeout) and handle.cancel():
             with self._cond:
-                waited = False
-                self._waiting += 1
-                try:
-                    while True:
-                        inst = self._try_take_locked(doomed)
-                        if inst is not None:
-                            break
-                        remaining = (None if timeout is None
-                                     else timeout - (time.monotonic() - t0))
-                        if remaining is not None and remaining <= 0:
-                            raise self._saturated_locked()
-                        waited = True
-                        self._cond.wait(remaining)
-                finally:
-                    self._waiting -= 1
-                cold = self._mark_acquired_locked(inst, waited)
-        finally:
-            # close corpses outside the lock: stats/close on a dead
-            # channel backend must never stall other acquires
-            self._fold_and_close(doomed, join_timeout=0.0)
-        # fabriclint: allow[clock] -- waiter deadlines/queue delay are wall-clock contracts
-        queue_delay = time.monotonic() - t0
-        self._h_queue_delay.observe(queue_delay)
+                raise self._saturated_locked(extra_waiters=1)
+        done.wait()          # the grant (or retire's failure) won the race
+        inst, queue_delay, cold, error = got[0]
+        if error is not None:
+            raise error
         return inst, queue_delay, cold
 
     def try_acquire(self) -> Optional[Tuple[PooledInstance, bool]]:
@@ -759,10 +731,10 @@ class InstancePool:
 
         Returns ``(instance, cold_start)`` when an idle instance (or an
         allowed scale-up slot) is immediately available, else None: the
-        caller then falls back to ``acquire``/``acquire_async``.  Never
-        jumps the queue: while async waiters are parked, callers get
-        None so admission order holds.  Runs the same opportunistic
-        keep-alive reap as blocking ``acquire`` — the fast path must
+        caller then parks with ``acquire_async``.  Never jumps the queue:
+        while waiters are parked, callers get None so admission order
+        holds.  Runs the same opportunistic keep-alive reap as
+        ``acquire_async`` — the fast path must
         not hand out an instance whose keep-alive already expired (a
         daemon tick may not have swept it yet), or lifecycle policy
         would silently depend on the admission mode."""
@@ -793,38 +765,35 @@ class InstancePool:
         expired waiters with ``PoolSaturated``.  The callback fires
         exactly once — ``cb(instance, queue_delay, cold, error)`` — or
         never, if the returned handle is cancelled first.  Like
-        ``acquire``/``try_acquire``, the immediate-grant probe reaps
-        expired idle instances first, so admission mode never changes
-        keep-alive semantics."""
+        ``try_acquire``, the immediate-grant probe reaps expired idle
+        instances first, so the admission route never changes keep-alive
+        semantics."""
         self.reap()
         # fabriclint: allow[clock] -- waiter deadlines/queue delay are wall-clock contracts
         t0 = time.monotonic()
         deadline = None if timeout is None else t0 + timeout
         waiter = _AsyncWaiter(cb=cb, enqueued=t0, deadline=deadline)
         doomed: List[PooledInstance] = []
+        grants: List = []
+        expired: List = []
         inst = None
-        cold = False
         with self._cond:
             if self._retired:
                 waiter.state = "failed"
                 waiter.error = self._saturated_locked()
+                expired.append(waiter)
             elif not self._async_waiters:
                 inst = self._try_take_locked(doomed, extra_waiters=1)
-                if inst is not None:
-                    waiter.state = "served"
-                    cold = self._mark_acquired_locked(inst, waited=False)
-            if waiter.state == "pending":
+            if inst is not None:
+                waiter.state = "served"
+                cold = self._mark_acquired_locked(inst, waited=False)
+                grants.append((waiter, inst, cold))
+            elif waiter.state == "pending":
                 self._async_waiters.append(waiter)
+                # one more waiter may lift demand to scale_up_queue_depth
+                grants, expired = self._serve_async_locked(doomed)
         self._fold_and_close(doomed, join_timeout=0.0)
-        if waiter.state == "served":
-            # fabriclint: allow[clock] -- waiter deadlines/queue delay are wall-clock contracts
-            queue_delay = time.monotonic() - t0
-            self._h_queue_delay.observe(queue_delay)
-            self._fire_cb(waiter, inst, queue_delay, cold, None)
-        elif waiter.state == "failed":
-            # fabriclint: allow[clock] -- waiter deadlines/queue delay are wall-clock contracts
-            self._fire_cb(waiter, None, time.monotonic() - t0, False,
-                          waiter.error)
+        self._dispatch_async(grants, expired)
         return AcquireWaiter(self, waiter)
 
     def _fire_cb(self, waiter: _AsyncWaiter, inst: Optional[PooledInstance],
@@ -892,11 +861,10 @@ class InstancePool:
         self._dispatch_async(grants, expired)
 
     def sweep_waiters(self, now: Optional[float] = None) -> int:
-        """Fail closure-parked waiters past their deadline with
-        ``PoolSaturated`` and opportunistically serve any that capacity
-        has appeared for (self-healing against a missed pump).  Driven
-        by the ``AdaptDaemon`` tick — the async analogue of the blocking
-        ``acquire``'s own timeout bookkeeping.  ``now`` is in the
+        """Fail parked waiters past their deadline with ``PoolSaturated``
+        and opportunistically serve any that capacity has appeared for
+        (self-healing against a missed pump).  Driven by the
+        ``AdaptDaemon`` tick.  ``now`` is in the
         ``time.monotonic`` domain (waiter deadlines are wall-clock
         contracts, not pool-clock policy time)."""
         now = time.monotonic() if now is None else now
@@ -932,7 +900,6 @@ class InstancePool:
             inst.state = InstanceState.REAPED
             del self._instances[inst.instance_id]
             self._c_dead.inc()
-            self._cond.notify()       # capacity freed: a waiter may scale up
         self._fold_and_close([inst], join_timeout=0.0)
         self._pump_async()            # freed capacity may admit a waiter
         return True
@@ -956,16 +923,14 @@ class InstancePool:
                     self._c_dead.inc()
                 else:
                     self._c_reaped.inc()
-                self._cond.notify()   # capacity freed: a waiter may scale up
             else:
                 inst.state = InstanceState.IDLE
                 inst.last_used = self.clock()
                 self._idle.append(inst)
                 # direct handoff: serve the parked waiter queue under
                 # THIS lock hold — the freed instance reaches the next
-                # closure-parked request without an executor round-trip
+                # parked request without an executor round-trip
                 grants, expired = self._serve_async_locked(doomed)
-                self._cond.notify()
             closing = self._retired or dead
         if closing:
             self._fold_and_close([inst], join_timeout=0.0)
@@ -982,14 +947,14 @@ class InstancePool:
         object so every closure holding a reference (the default runtime
         factory, scheduler-registered factories) sees the new values —
         this is how a trace-learned ``HistoryPolicy`` retunes a running
-        pool.  Waiters are woken: a raised ``max_instances`` lets a queued
-        acquire scale up immediately; a lowered cap or keep-alive takes
-        effect at the next reap (busy instances are never force-killed)."""
+        pool.  Parked waiters are served: a raised ``max_instances`` lets
+        a queued acquire scale up immediately; a lowered cap or
+        keep-alive takes effect at the next reap (busy instances are
+        never force-killed)."""
         with self._cond:
             old = replace(self.config)
             for f in fields(PoolConfig):
                 setattr(self.config, f.name, getattr(config, f.name))
-            self._cond.notify_all()
         self._pump_async()        # a raised cap may admit parked waiters
         return old
 
@@ -1037,7 +1002,6 @@ class InstancePool:
                     len(self._instances) < self.config.max_instances:
                 inst = self._create_locked()   # stays IDLE and acquirable
                 self._c_provisioned.inc()
-                self._cond.notify()
                 targets = [inst]
             if not targets and level >= WarmthLevel.HOT \
                     and self.config.prewarm_busy_fallback:
@@ -1126,8 +1090,7 @@ class InstancePool:
             out = {
                 "instances": len(self._instances),
                 "idle": len(self._idle),
-                "waiting": self._waiting + len(self._async_waiters),
-                "async_waiting": len(self._async_waiters),
+                "waiting": len(self._async_waiters),
                 "cold_starts": self.cold_starts,
                 "warm_acquires": self.warm_acquires,
                 "queued_acquires": self.queued_acquires,

@@ -7,22 +7,20 @@ Accountant's confidence/service-class/accuracy policy.  Unlike the seed
 (one synchronous ``Runtime`` per function), each registered function is
 backed by an ``InstancePool`` (repro.core.pool):
 
-* ``invoke``  — acquire an instance (possibly cold-starting or queueing),
-  run, release; queueing delay and cold starts are reported to the
+* ``invoke``  — predict inline, acquire an instance (blocking: possibly
+  cold-starting or queueing), then run the shared run-and-release tail
+  ``_run_acquired``; queueing delay and cold starts are reported to the
   Accountant alongside service time.
 * ``submit`` / ``submit_chain`` — admit invocations concurrently; returns
-  a Future.  ``submit`` is a *single-submission fast path*: it calls
-  ``InstancePool.try_acquire`` inline on the caller thread and, on a warm
-  hit, hands only the run-and-release tail to the router executor — one
-  hop, ``queue`` phase ≈ the executor handoff.  When nothing is
-  immediately available the request parks a **closure** in the pool's
-  ``acquire_async`` waiter queue (no router thread blocks on the
-  condition variable); ``release`` hands the freed instance straight to
-  it.  Freshen prediction (``on_invocation_start``) runs on a dedicated
-  single-worker executor off the critical path — observation order is
-  preserved (FIFO), admission latency stops paying for it.  Constructing
-  the scheduler with ``fast_path=False`` restores the PR 8 two-hop
-  admission (the measured baseline in ``benchmarks/hot_path.py``).
+  a Future.  ``submit`` calls ``InstancePool.try_acquire`` inline on the
+  caller thread and, on a warm hit, hands only ``_run_acquired`` to the
+  router executor — one hop, ``queue`` phase ≈ the executor handoff.
+  When nothing is immediately available the request parks a **closure**
+  in the pool's waiter queue (``acquire_async``: no router thread
+  blocks); ``release`` hands the freed instance straight to it.  Freshen
+  prediction (``on_invocation_start``) runs on a dedicated single-worker
+  executor off the critical path — observation order is preserved
+  (FIFO), admission latency stops paying for it.
 * ``submit`` on an unregistered function raises ``UnknownFunction`` at
   admission time (synchronously) rather than surfacing a bare KeyError
   later inside the Future.
@@ -142,8 +140,7 @@ class FreshenScheduler:
                  event_window: int = 4096,
                  warmth_policy: Optional["WarmthPolicy"] = None,
                  tracer: Optional[Tracer] = None,
-                 metrics: Optional[MetricsRegistry] = None,
-                 fast_path: bool = True):
+                 metrics: Optional[MetricsRegistry] = None):
         self.predictor = predictor or HybridPredictor()
         self.accountant = accountant or Accountant()
         self.pool_config = pool_config or PoolConfig()
@@ -160,8 +157,8 @@ class FreshenScheduler:
         self._m_e2e = self.metrics.histogram("invoke.e2e_seconds")
         self._m_queue = self.metrics.histogram("invoke.queue_delay_seconds")
         # admission-path split: fast = inline try_acquire hit (run-only
-        # work dispatched), slow = parked in the pool's waiter queue or
-        # fell back to a blocking acquire (spill/legacy path)
+        # work dispatched), slow = a miss parked in the pool's waiter
+        # queue (a callback, or the spill path's blocking acquire)
         self._m_fast = self.metrics.counter("invoke.fast_path")
         self._m_slow = self.metrics.counter("invoke.slow_path")
         # None = binary warmth (every prewarm targets HOT — seed behavior);
@@ -185,10 +182,6 @@ class FreshenScheduler:
         self._scopes: Dict[str, tuple] = {}      # chain-level shared scopes
         self._lock = threading.Lock()
         self._router: Optional[ThreadPoolExecutor] = None
-        # single-submission fast path toggle: False restores the PR 8
-        # two-hop admission (every submit routed through invoke) — the
-        # legacy arm benchmarks/hot_path.py measures against
-        self.fast_path = fast_path
         # freshen prediction off the critical path: one worker, so
         # predictor.observe keeps its arrival order (the Markov chain
         # detector is order-sensitive) while admission stops paying for
@@ -417,40 +410,22 @@ class FreshenScheduler:
             with span.phase("acquire"):
                 inst, queue_delay, cold = pool.acquire(
                     timeout=acquire_timeout)
-            span.annotate(queue_delay=queue_delay, cold=cold)
-            # fabriclint: allow[clock] -- measured service/freshen time is a wall-clock contract
-            t0 = time.monotonic()
-            try:
-                # activate so Runtime's lazy boot path attaches
-                # boot_process/boot_init phases to this invocation
-                with span.phase("run"), span.active():
-                    result = inst.runtime.run(args)
-            finally:
-                with span.phase("release"):
-                    pool.release(inst)
         except BaseException as exc:
             span.finish(error=type(exc).__name__)
             raise
-        # accounting only on success (seed semantics): a raising function
-        # body must not be billed, skew latency percentiles, or credit
-        # pending freshens as useful
-        # fabriclint: allow[clock] -- measured service/freshen time is a wall-clock contract
-        service = time.monotonic() - t0
-        self._m_e2e.observe(queue_delay + service)
-        self._m_queue.observe(queue_delay)
-        span.finish()
-        self.accountant.record_invocation(
-            pool.spec.app, fn, service,
-            queue_delay=queue_delay, cold_start=cold)
-        return result
+        return self._run_acquired(fn, pool, inst, args, span, queue_delay,
+                                  cold, queue_phase=False)
 
     def _run_acquired(self, fn: str, pool: InstancePool, inst, args,
-                      span, queue_delay: float, cold: bool):
-        """Run-and-release tail of an admission whose acquire already
-        happened (fast-path ``try_acquire`` hit or ``acquire_async``
-        grant).  Same bookkeeping contract as ``invoke``: accounting on
-        success only, span finished on every path."""
-        if span.enabled and span.submitted_at is not None:
+                      span, queue_delay: float, cold: bool,
+                      queue_phase: bool = True):
+        """Run-and-release tail of every admission: an ``invoke``
+        acquire, a ``submit`` ``try_acquire`` hit or an ``acquire_async``
+        grant.  Accounting on success only (a raising function body must
+        not be billed, skew latency percentiles, or credit pending
+        freshens as useful); span finished on every path.
+        ``queue_phase=False``: the caller already recorded the hop."""
+        if queue_phase and span.enabled and span.submitted_at is not None:
             # admission -> this thread: the only hop the fast path pays
             span.phase_from("queue", span.submitted_at)
         span.annotate(queue_delay=queue_delay, cold=cold)
@@ -458,6 +433,8 @@ class FreshenScheduler:
         t0 = time.monotonic()
         try:
             try:
+                # activate so Runtime's lazy boot path attaches
+                # boot_process/boot_init phases to this invocation
                 with span.phase("run"), span.active():
                     result = inst.runtime.run(args)
             finally:
@@ -499,25 +476,19 @@ class FreshenScheduler:
                _span=None) -> Future:
         """Admit one invocation; returns a Future for the function result.
 
-        Single-submission fast path: ``try_acquire`` runs inline on the
-        caller thread — a warm hit dispatches only the run-and-release
-        tail to the router (one hop, ``invoke.fast_path``).  On a miss
-        (``invoke.slow_path``): with an ``acquire_timeout`` the request
-        takes the legacy blocking-acquire path unchanged, so spill
-        semantics (``PoolSaturated`` surfacing on the Future within the
-        deadline) are exactly the PR 8 behavior; without one it parks a
-        closure in the pool's admission-ordered ``acquire_async`` queue
-        and the next ``release`` hands it the freed instance directly.
-        Raises ``UnknownFunction`` synchronously for an unregistered
-        ``fn``."""
+        ``try_acquire`` runs inline on the caller thread — a warm hit
+        dispatches only the run-and-release tail to the router (one hop,
+        ``invoke.fast_path``).  On a miss (``invoke.slow_path``): without
+        an ``acquire_timeout`` it parks a closure in the pool's
+        admission-ordered waiter queue and the next ``release`` hands it
+        the freed instance directly; with one (the cluster's spill) a
+        router thread runs ``invoke``, whose blocking ``acquire`` waits
+        in the same queue and raises ``PoolSaturated`` onto the Future
+        within the deadline.  Raises ``UnknownFunction`` synchronously
+        for an unregistered ``fn``."""
         pool = self._pool_or_raise(fn)
         if _span is None:
             _span = self.tracer.invocation(fn, app=pool.spec.app)
-        if not self.fast_path:
-            _span.mark_submitted()
-            return self._ensure_router().submit(
-                self.invoke, fn, args, freshen_successors, acquire_timeout,
-                _span)
         with _span.phase("acquire"):
             grabbed = pool.try_acquire()
         _span.mark_submitted()
@@ -530,9 +501,9 @@ class FreshenScheduler:
                 self._run_acquired, fn, pool, inst, args, _span, 0.0, cold)
         self._m_slow.inc()
         if acquire_timeout is not None:
-            # spill path unchanged: blocking acquire with a deadline in a
-            # router thread (the cluster's retry chain needs PoolSaturated
-            # raised from the acquire, not a swept waiter)
+            # spill: a blocking acquire with a deadline on a router thread
+            # (the cluster's retry chain needs PoolSaturated raised by the
+            # deadline itself, not by a daemon sweep)
             return self._ensure_router().submit(
                 self.invoke, fn, args, freshen_successors, acquire_timeout,
                 _span)
@@ -547,7 +518,7 @@ class FreshenScheduler:
                 fut.set_exception(error)
                 return
             # the waiter left the pool queue before this callback runs, so
-            # shutdown's drain (which watches async_waiting_count) can kill
+            # shutdown's drain (which watches waiting_count) can kill
             # the router inside that window; don't _ensure_router here —
             # that would resurrect a leaked executor after shutdown
             with self._lock:
@@ -620,11 +591,11 @@ class FreshenScheduler:
         every pool's idle instances once in-flight work has drained —
         terminating subprocess backend workers so platforms never leak
         processes.  Pools stay usable afterwards (they re-provision).
-        Closure-parked admissions are not router tasks yet, so the drain
-        first waits for the pools' waiter queues to empty (releases from
+        Parked admissions are not router tasks yet, so the drain first
+        waits for the pools' waiter queues to empty (releases from
         in-flight runs serve them) before stopping the router."""
         if wait:
-            while any(p.async_waiting_count()
+            while any(p.waiting_count()
                       for p in list(self.pools.values())):
                 time.sleep(0.001)
         with self._lock:

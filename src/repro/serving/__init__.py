@@ -1,5 +1,4 @@
-from repro.serving.batching import (Batcher, EndpointBatcher,  # noqa: F401
-                                    pad_batch)
+from repro.serving.batching import EndpointBatcher, pad_batch  # noqa: F401
 from repro.serving.datastore import TieredDatastore  # noqa: F401
 from repro.serving.engine import ModelEndpoint, ServingEngine, WarmBudget  # noqa: F401
 from repro.serving.executor import Executor  # noqa: F401

@@ -2,26 +2,20 @@
 (padding the tail) so the compiled executable shape is reused — serverless
 "requests" become batched model invocations.
 
-Two batchers live here:
-
-* ``Batcher`` — the original fabric-blind batcher: fixed target size,
-  flush on fullness or deadline, ``handler`` runs on the worker thread.
-  Kept as-is for callers that batch outside the platform (examples,
-  tests); its per-batch fill counts now land in a bounded registry
-  ``Histogram`` instead of an unbounded list.
-* ``EndpointBatcher`` — the pool-aware batcher ``ServingEngine.deploy``
-  installs in front of a deployed endpoint.  It dispatches at once when
-  the fabric has a free slot (the queued rows spread over the free
-  slots), dispatches a full batch at once, and otherwise holds rows while
-  one of its own batches is in flight, so they leave together when that
-  batch frees its instance.  Each batch runs as ONE pooled invocation
-  (one acquire/release, one span), and ``PoolSaturated`` is
-  backpressure: the batch is requeued at the front and retried, never
-  surfaced to callers as an error.
+``EndpointBatcher`` is the one batcher: ``ServingEngine.deploy`` installs
+it in front of a deployed endpoint.  It dispatches at once when the
+fabric has a free slot (the queued rows spread over the free slots),
+dispatches a full batch at once, and otherwise holds rows while one of
+its own batches is in flight, so they leave together when that batch
+frees its instance.  Each batch runs as ONE pooled invocation (one
+acquire/release, one span), and ``PoolSaturated`` is backpressure: the
+batch is requeued at the front and retried, never surfaced to callers as
+an error.  Without a ``capacity`` signal it is a plain deadline batcher:
+batches fill toward ``batch_size`` and leave ``max_wait`` after their
+oldest row.
 """
 from __future__ import annotations
 
-import queue
 import threading
 import time
 from collections import deque
@@ -44,158 +38,6 @@ class Request:
     payload: Any
     future: Future = field(default_factory=Future)
     submitted_at: float = field(default_factory=time.monotonic)
-
-
-_CLOSE = object()       # sentinel: wakes the worker immediately on close()
-
-
-class Batcher:
-    """Groups requests into batches of ``batch_size``; flushes on fullness or
-    ``max_wait`` seconds after the *first* request of a partial batch.
-    ``handler(payloads: list) -> list`` runs on the worker thread.
-
-    ``close()`` is graceful: a sentinel wakes the worker, every request
-    already queued is flushed through the handler (no caller is ever left
-    hanging on a Future), and only then does the worker exit.  Requests
-    submitted after close raise ``RuntimeError``."""
-
-    def __init__(self, batch_size: int, handler: Callable[[List[Any]], List[Any]],
-                 max_wait: float = 0.01, name: str = "batcher",
-                 clock: Callable[[], float] = time.monotonic):
-        self.batch_size = batch_size
-        self.handler = handler
-        self.max_wait = max_wait
-        # paces queue.get timeouts, so the default must be the wall
-        # clock; injectable for tests
-        self.clock = clock
-        self._q: queue.Queue = queue.Queue()
-        self._stop = False
-        self._lifecycle = threading.Lock()   # makes submit-vs-close atomic
-        self.metrics = MetricsRegistry(f"{name}.")
-        self._c_batches = self.metrics.counter("batches")
-        self._c_requests = self.metrics.counter("requests")
-        self._h_fill = self.metrics.histogram("batch.fill")
-        # bounded recency view (tests index [-1] / max() over it); the
-        # histogram above carries the exact lifetime count and sum — a
-        # long-running platform no longer accretes one int per batch
-        self.batch_fill: Deque[int] = deque(maxlen=FILL_VIEW_LIMIT)
-        self._th = threading.Thread(target=self._loop, daemon=True)
-        self._th.start()
-
-    # legacy counter attributes, now registry-backed views
-    @property
-    def batches_processed(self) -> int:
-        return self._c_batches.value
-
-    @property
-    def requests_processed(self) -> int:
-        return self._c_requests.value
-
-    def submit(self, payload: Any) -> Future:
-        # check+put under the lifecycle lock: a submit can never slip its
-        # request into the queue after close() has finished draining
-        with self._lifecycle:
-            if self._stop:
-                raise RuntimeError("Batcher is closed")
-            req = Request(payload)
-            self._q.put(req)
-        return req.future
-
-    def _flush(self, batch: List[Request]):
-        if not batch:
-            return
-        try:
-            results = self.handler([r.payload for r in batch])
-            if len(results) != len(batch):
-                raise RuntimeError(
-                    f"handler returned {len(results)} results for "
-                    f"{len(batch)} requests")
-            for r, res in zip(batch, results):
-                if not r.future.done():      # caller may have cancelled
-                    r.future.set_result(res)
-        except BaseException as exc:
-            for r in batch:
-                if not r.future.done():
-                    r.future.set_exception(exc)
-        self._c_batches.inc()
-        self._c_requests.inc(len(batch))
-        self._h_fill.observe(len(batch))
-        self.batch_fill.append(len(batch))
-
-    def _loop(self):
-        closing = False
-        while not closing:
-            try:
-                first = self._q.get(timeout=0.1)
-            except queue.Empty:
-                if self._stop:
-                    break
-                continue
-            if first is _CLOSE:
-                break
-            # Partial-batch deadline: starts at the FIRST request and is
-            # honored exactly — a batch never waits longer than max_wait,
-            # even when requests keep trickling in.
-            batch: List[Request] = [first]
-            deadline = self.clock() + self.max_wait
-            while len(batch) < self.batch_size:
-                remaining = deadline - self.clock()
-                if remaining <= 0:
-                    break
-                try:
-                    req = self._q.get(timeout=remaining)
-                except queue.Empty:
-                    break
-                if req is _CLOSE:
-                    closing = True
-                    break
-                batch.append(req)
-            self._flush(batch)
-        # Drain: flush everything that was queued before (or raced with)
-        # close so no submitted Future is ever dropped.
-        tail: List[Request] = []
-        while True:
-            try:
-                req = self._q.get_nowait()
-            except queue.Empty:
-                break
-            if req is _CLOSE:
-                continue
-            tail.append(req)
-            if len(tail) == self.batch_size:
-                self._flush(tail)
-                tail = []
-        self._flush(tail)
-
-    def close(self, timeout: float = 5.0):
-        with self._lifecycle:
-            if self._stop:
-                return
-            self._stop = True
-        self._q.put(_CLOSE)
-        self._th.join(timeout=timeout)
-        if self._th.is_alive():
-            # Worker is merely slow (long handler): it will still drain the
-            # queue itself; failing stragglers here would race its drain
-            # loop and break the no-dropped-request guarantee.
-            return
-        # Worker is dead: fail any stragglers rather than hang their
-        # callers forever.
-        while True:
-            try:
-                req = self._q.get_nowait()
-            except queue.Empty:
-                break
-            if req is _CLOSE:
-                continue
-            if not req.future.done():
-                req.future.set_exception(RuntimeError("Batcher closed"))
-
-    def stats(self):
-        summary = self._h_fill.summary()
-        return {"batches": self.batches_processed,
-                "requests": self.requests_processed,
-                "mean_fill": summary["mean"] if summary["count"] else 0.0}
 
 
 class EndpointBatcher:

@@ -15,6 +15,8 @@ _SANITIZE = _sanitizer.enabled_by_env()
 if _SANITIZE:
     _SAN_GRAPH = _sanitizer.install()
 
+from concurrent.futures import Future  # noqa: E402
+
 import jax  # noqa: E402
 import pytest  # noqa: E402
 
@@ -58,3 +60,16 @@ class FakeClock:
 @pytest.fixture
 def fake_clock() -> FakeClock:
     return FakeClock()
+
+
+def sync_batches(handler):
+    """``EndpointBatcher`` ``run_batch`` that runs ``handler(payloads)``
+    on the batcher's thread and returns an already-resolved Future."""
+    def run_batch(payloads):
+        fut = Future()
+        try:
+            fut.set_result(handler(payloads))
+        except BaseException as e:       # noqa: BLE001
+            fut.set_exception(e)
+        return fut
+    return run_batch

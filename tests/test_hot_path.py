@@ -1,14 +1,14 @@
-"""Single-submission hot path (PR 9): non-blocking/closure-parked pool
-admission, the scheduler fast path and its counters, chain tracing
+"""Single-submission hot path: non-blocking/closure-parked pool
+admission and the one waiter queue it shares with the blocking acquire,
+the scheduler fast path and its counters, chain tracing
 parity, UnknownFunction, the pool-aware EndpointBatcher, daemon waiter
 sweeps, and shutdown draining of parked admissions."""
 import threading
 import time
-from concurrent.futures import Future
 
 import pytest
 
-from conftest import FakeClock
+from conftest import FakeClock, sync_batches
 
 from repro.core import (FreshenScheduler, FunctionSpec, InstancePool,
                         PoolConfig, PoolSaturated, UnknownFunction)
@@ -105,7 +105,7 @@ def test_release_hands_instance_to_waiters_in_admission_order():
     first, second = [], []
     pool.acquire_async(_cb(first))
     pool.acquire_async(_cb(second))
-    assert pool.async_waiting_count() == 2
+    assert pool.waiting_count() == 2
     assert pool.try_acquire() is None    # no queue jumping past waiters
     pool.release(inst)
     assert len(first) == 1 and not second    # FIFO: head served first
@@ -114,6 +114,24 @@ def test_release_hands_instance_to_waiters_in_admission_order():
     pool.release(got)
     assert len(second) == 1 and second[0][0] is inst
     pool.release(second[0][0])
+    pool.close()
+
+
+def test_parked_waiter_reaching_scale_up_depth_provisions():
+    """A waiter that lifts the queue to ``scale_up_queue_depth`` scales the
+    pool up, and the new instance goes to the head of the queue."""
+    pool = _pool(cap=2, scale_up_queue_depth=2)
+    inst, _ = pool.try_acquire()
+    first, second = [], []
+    pool.acquire_async(_cb(first))       # demand 1: below the depth
+    assert not first and pool.size() == 1
+    pool.acquire_async(_cb(second))      # demand 2: provisions
+    assert len(first) == 1 and first[0][0] is not inst and not second
+    assert pool.size() == 2 and pool.waiting_count() == 1
+    pool.release(inst)
+    assert len(second) == 1 and second[0][0] is inst
+    pool.release(first[0][0])
+    pool.release(inst)
     pool.close()
 
 
@@ -213,7 +231,7 @@ def test_submit_slow_path_parks_closure_and_resolves():
         # instance is already BUSY (gated) when this returns
         f1 = sched.submit("g", 1, freshen_successors=False)
         f2 = sched.submit("g", 2, freshen_successors=False)   # parks
-        assert sched.pools["g"].async_waiting_count() == 1
+        assert sched.pools["g"].waiting_count() == 1
         gate.set()
         assert f1.result(5) == 1 and f2.result(5) == 2
         snap = sched.metrics_snapshot()
@@ -225,18 +243,68 @@ def test_submit_slow_path_parks_closure_and_resolves():
         sched.shutdown()
 
 
-def test_fast_path_false_restores_two_hop_admission():
-    sched = FreshenScheduler(pool_config=PoolConfig(max_instances=1),
-                             fast_path=False)
-    sched.register(_spec("f"))
-    try:
-        assert sched.submit("f", 3, freshen_successors=False).result(5) \
-            == ("ok", 3)
-        snap = sched.metrics_snapshot()
-        assert snap["scheduler.invoke.fast_path"] == 0
-        assert snap["scheduler.invoke.slow_path"] == 0
-    finally:
-        sched.shutdown()
+def test_blocking_acquire_and_callback_share_one_fifo():
+    """A blocking ``acquire`` parks in the same queue as callbacks: a
+    callback parked behind it waits its turn."""
+    pool = _pool(cap=1)
+    inst, _ = pool.try_acquire()
+    held, granted, let_go = [], threading.Event(), threading.Event()
+
+    def blocker():
+        got, _, _ = pool.acquire(timeout=5.0)
+        held.append(got)
+        granted.set()
+        let_go.wait(5)
+        pool.release(got)
+
+    th = threading.Thread(target=blocker)
+    th.start()
+    deadline = time.monotonic() + 5.0
+    while pool.waiting_count() == 0 and time.monotonic() < deadline:
+        time.sleep(0.001)
+    later = []
+    pool.acquire_async(_cb(later))
+    assert pool.waiting_count() == 2
+    pool.release(inst)
+    assert granted.wait(5) and held == [inst]    # the head was the thread
+    assert not later and pool.waiting_count() == 1
+    let_go.set()
+    th.join(5)
+    assert len(later) == 1 and later[0][0] is inst and later[0][3] is None
+    pool.release(inst)
+    pool.close()
+
+
+def test_timed_out_acquire_leaves_the_queue():
+    """An ``acquire`` that times out raises ``PoolSaturated`` and leaves
+    no waiter behind; the next release goes to the next waiter."""
+    pool = _pool(cap=1)
+    inst, _ = pool.try_acquire()
+    with pytest.raises(PoolSaturated):
+        pool.acquire(timeout=0.01)
+    assert pool.waiting_count() == 0
+    errors = []
+
+    def blocker():
+        try:
+            pool.acquire(timeout=0.05)
+        except PoolSaturated as e:
+            errors.append(e)
+
+    th = threading.Thread(target=blocker)
+    th.start()
+    deadline = time.monotonic() + 5.0
+    while pool.waiting_count() == 0 and time.monotonic() < deadline:
+        time.sleep(0.001)
+    later = []
+    pool.acquire_async(_cb(later))       # parked behind the blocked thread
+    th.join(5)
+    assert len(errors) == 1 and errors[0].queue_depth >= 1
+    assert pool.waiting_count() == 1     # only the callback is left
+    pool.release(inst)
+    assert len(later) == 1 and later[0][0] is inst
+    pool.release(inst)
+    pool.close()
 
 
 def test_unknown_function_raises_at_admission_time():
@@ -317,18 +385,6 @@ def test_daemon_step_sweeps_expired_waiters():
 # EndpointBatcher
 
 
-def _sync_batches(handler):
-    """run_batch closure resolving synchronously through ``handler``."""
-    def run_batch(payloads):
-        fut = Future()
-        try:
-            fut.set_result(handler(payloads))
-        except BaseException as e:       # noqa: BLE001
-            fut.set_exception(e)
-        return fut
-    return run_batch
-
-
 def test_endpoint_batcher_fills_and_resolves_in_order():
     fills = []
 
@@ -336,7 +392,7 @@ def test_endpoint_batcher_fills_and_resolves_in_order():
         fills.append(len(payloads))
         return [p * 2 for p in payloads]
 
-    b = EndpointBatcher("t", _sync_batches(handler), batch_size=4,
+    b = EndpointBatcher("t", sync_batches(handler), batch_size=4,
                         max_wait=0.02)
     futs = [b.submit(i) for i in range(10)]
     assert [f.result(5) for f in futs] == [2 * i for i in range(10)]
@@ -373,7 +429,7 @@ def test_endpoint_batcher_adapts_fill_to_fabric_capacity():
                 fills.append(len(payloads))
             return list(payloads)
 
-        b = EndpointBatcher("t", _sync_batches(handler), batch_size=8,
+        b = EndpointBatcher("t", sync_batches(handler), batch_size=8,
                             max_wait=0.01, capacity=lambda s=slots: s)
         try:
             hold = b.submit("hold")
@@ -401,7 +457,7 @@ def test_endpoint_batcher_backpressures_on_saturation():
             raise PoolSaturated("t", queue_depth=1)
         return list(payloads)
 
-    b = EndpointBatcher("t", _sync_batches(handler), batch_size=4,
+    b = EndpointBatcher("t", sync_batches(handler), batch_size=4,
                         max_wait=0.005, retry_interval=0.002)
     futs = [b.submit(i) for i in range(4)]
     assert [f.result(5) for f in futs] == list(range(4))
@@ -470,10 +526,10 @@ def test_endpoint_batcher_holds_rows_behind_a_busy_instance(queued,
         assert entered.wait(5)
         futs = [b.submit(i) for i in range(1, queued + 1)]
         deadline = time.monotonic() + 5
-        while (b.queue_depth(), pool.async_waiting_count()) != (
+        while (b.queue_depth(), pool.waiting_count()) != (
                 queued % 4, queued // 4):
             assert time.monotonic() < deadline, (b.queue_depth(),
-                                                 pool.async_waiting_count())
+                                                 pool.waiting_count())
             time.sleep(0.001)
         assert b.stats()["batches"] == 0     # nothing has left since
         t0 = time.monotonic()
@@ -492,7 +548,7 @@ def test_endpoint_batcher_holds_rows_behind_a_busy_instance(queued,
 
 def test_endpoint_batcher_free_slot_dispatches_at_once():
     """A free slot takes a lone row at once: ``max_wait`` is not waited."""
-    b = EndpointBatcher("t", _sync_batches(list), batch_size=4,
+    b = EndpointBatcher("t", sync_batches(list), batch_size=4,
                         max_wait=5.0, capacity=lambda: 1)
     try:
         t0 = time.monotonic()
@@ -506,7 +562,7 @@ def test_endpoint_batcher_free_slot_dispatches_at_once():
 
 def test_endpoint_batcher_batch_of_one_dispatches_every_row_at_once():
     """At ``batch_size`` 1 every row is a full batch, slot or none."""
-    b = EndpointBatcher("t", _sync_batches(list), batch_size=1,
+    b = EndpointBatcher("t", sync_batches(list), batch_size=1,
                         max_wait=5.0, capacity=lambda: 0)
     try:
         t0 = time.monotonic()
@@ -523,7 +579,7 @@ def test_endpoint_batcher_deadline_guard_without_free_slot():
     """No slot free and nothing of the batcher in flight (a prewarm or a
     direct submit holds the instance): the row still leaves after
     ``max_wait`` from its submit."""
-    b = EndpointBatcher("t", _sync_batches(list), batch_size=4,
+    b = EndpointBatcher("t", sync_batches(list), batch_size=4,
                         max_wait=0.05, capacity=lambda: 0)
     try:
         t0 = time.monotonic()
@@ -542,7 +598,7 @@ def test_endpoint_batcher_close_drains_pending():
         slow.wait(0.01)
         return list(payloads)
 
-    b = EndpointBatcher("t", _sync_batches(handler), batch_size=2,
+    b = EndpointBatcher("t", sync_batches(handler), batch_size=2,
                         max_wait=0.5)    # long wait: close must not stall
     futs = [b.submit(i) for i in range(5)]
     b.close()

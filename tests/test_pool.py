@@ -147,7 +147,8 @@ def test_acquire_timeout_raises_when_saturated():
 
 def test_scale_up_queue_depth_throttles_growth():
     """With depth=2 one waiter queues on the single busy instance; the pool
-    only provisions instance #2 once a second simultaneous waiter arrives."""
+    only provisions instance #2 once a second simultaneous waiter arrives,
+    and hands it to the head of the queue."""
     pool = InstancePool(_noop_spec(), PoolConfig(max_instances=4,
                                                  scale_up_queue_depth=2))
     a, _, _ = pool.acquire()
@@ -168,11 +169,11 @@ def test_scale_up_queue_depth_throttles_growth():
     assert t1.is_alive() and pool.size() == 1
     t2 = threading.Thread(target=grab)
     t2.start()                               # second waiter crosses the depth
+    t1.join(timeout=10.0)                    # FIFO: the head gets it
+    assert not t1.is_alive() and pool.size() == 2   # scaled up for the burst
+    pool.release(b)                          # frees the second waiter
     t2.join(timeout=10.0)
-    assert pool.size() == 2                  # scaled up for the burst
-    pool.release(b)                          # frees the first waiter too
-    t1.join(timeout=10.0)
-    assert not t1.is_alive() and len(got) == 2
+    assert not t2.is_alive() and len(got) == 2
 
 
 # ----------------------------------------------------------------------

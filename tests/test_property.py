@@ -324,7 +324,7 @@ def test_async_admission_interleavings(ops):
     def check():
         # a parked waiter next to an idle instance means starvation:
         # release hands off directly and try_acquire never queue-jumps
-        assert not (pool.idle_count() > 0 and pool.async_waiting_count() > 0)
+        assert not (pool.idle_count() > 0 and pool.waiting_count() > 0)
         for r in records:
             assert r["fired"] <= 1                      # at most once, ever
             if r["cancelled"]:
@@ -373,7 +373,7 @@ def test_async_admission_interleavings(ops):
         grants = sum(1 for r in records if r["inst"] is not None)
         # every admission — inline hit or async grant — billed exactly once
         assert s["cold_starts"] + s["warm_acquires"] == grants + try_hits[0]
-        assert pool.async_waiting_count() == 0
+        assert pool.waiting_count() == 0
     finally:
         while held:
             pool.release(held.pop())

@@ -8,9 +8,12 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from conftest import sync_batches
+
 from repro.configs import get_config
-from repro.serving import (Batcher, Executor, ModelEndpoint, ServingEngine,
-                           TieredDatastore, WarmBudget, WeightStore)
+from repro.serving import (EndpointBatcher, Executor, ModelEndpoint,
+                           ServingEngine, TieredDatastore, WarmBudget,
+                           WeightStore)
 
 
 @pytest.fixture(scope="module")
@@ -125,7 +128,8 @@ def test_batcher_groups_requests():
         calls.append(len(payloads))
         return [p * 2 for p in payloads]
 
-    b = Batcher(batch_size=4, handler=handler, max_wait=0.05)
+    b = EndpointBatcher("t", sync_batches(handler), batch_size=4,
+                        max_wait=0.05)
     futs = [b.submit(i) for i in range(10)]
     results = [f.result(timeout=5) for f in futs]
     assert results == [i * 2 for i in range(10)]
